@@ -24,6 +24,7 @@ from repro.core import Box, Checkpoint
 from repro.core.elastic import dp_degree, shrink_mesh
 from repro.core.env import CraftEnv
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.sharding.logical import LogicalRules, shard_specs
 
@@ -46,7 +47,7 @@ def main() -> None:
                             "CRAFT_USE_SCR": "0"})
     cfg = get_config("h2o-danube-1.8b", tiny=True)
 
-    mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+    mesh_a = make_mesh((4, 2), ("data", "model"))
     params_a = params_on_mesh(cfg, mesh_a)
     print(f"wrote state on mesh {dict(zip(mesh_a.axis_names, mesh_a.devices.shape))} "
           f"(DP degree {dp_degree(mesh_a)})")

@@ -69,7 +69,18 @@ def register_config(arch_id: str, cfg: ModelConfig,
     _EXTRA[arch_id] = (cfg, tiny if tiny is not None else cfg)
 
 
-def get_config(arch_id: str, tiny: bool = False) -> ModelConfig:
+def get_config(arch_id: str, tiny: bool = False,
+               preset: Optional[str] = None) -> ModelConfig:
+    """The architecture's published config, its ``TINY`` test config, or a
+    named preset of its module (``PRESETS``, e.g. a one-chip cut); a preset
+    takes precedence over ``tiny``."""
+    if preset is not None:
+        presets = getattr(_MODULES.get(arch_id), "PRESETS", {})
+        if preset not in presets:
+            raise KeyError(
+                f"arch {arch_id!r} has no preset {preset!r}; known: "
+                f"{', '.join(presets) or 'none'}")
+        return presets[preset]["config"]
     if arch_id in _EXTRA:
         return _EXTRA[arch_id][1 if tiny else 0]
     if arch_id not in _MODULES:

@@ -61,8 +61,22 @@ def _pack_words(x: jnp.ndarray, n_chunks: int, wpc: int) -> jnp.ndarray:
     flat = x.reshape(-1)
     itemsize = np.dtype(x.dtype).itemsize
     if itemsize < 4:
-        words = jax.lax.bitcast_convert_type(
-            flat.reshape(-1, 4 // itemsize), jnp.uint32)
+        # Combine k narrow items into one word with shifts over strided lane
+        # slices of a (rows, 128 * k) view.  Bit-casting a (n, k) view
+        # instead would give the TPU an array whose minor dim of k is
+        # padded to 128 lanes: 64x the shard's bytes for bf16.
+        k = 4 // itemsize
+        bits = 8 * itemsize
+        narrow = jax.lax.bitcast_convert_type(
+            flat, jnp.dtype(f"uint{bits}"))
+        pad = (-narrow.shape[0]) % (_LANES * k)
+        if pad:
+            narrow = jnp.pad(narrow, (0, pad))
+        rows = narrow.reshape(-1, _LANES * k)
+        words = rows[:, 0::k].astype(jnp.uint32)
+        for i in range(1, k):
+            words = words | (rows[:, i::k].astype(jnp.uint32) << (bits * i))
+        words = words.reshape(-1)[: -(-flat.shape[0] // k)]
     elif itemsize == 4:
         words = jax.lax.bitcast_convert_type(flat, jnp.uint32)
     else:
@@ -170,7 +184,13 @@ class DeviceSnapshotter:
             self._state[key] = st
 
         backend = jax.default_backend()
-        use_pallas = backend == "tpu" and wpc % _LANES == 0
+        use_pallas = backend == "tpu"
+        if use_pallas and wpc % _LANES:
+            # only a multi-chunk grid can get here: _grid pads one chunk
+            raise ValueError(
+                f"CRAFT_CHUNK_BYTES={self.chunk_bytes} is not a multiple of "
+                f"{4 * _LANES}: the TPU snapshot kernel needs whole "
+                f"{_LANES}-word lanes in every chunk")
         staged = self.staged if self.staged is not None else backend != "cpu"
         if not staged:
             # CPU: zero-copy view of the immutable buffer — snapshot-stable

@@ -19,7 +19,7 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 
 from repro.sharding.logical import LogicalRules, shard_specs
 
@@ -38,7 +38,8 @@ def shrink_mesh(n_devices: int, model_parallel: int,
     import numpy as np
 
     arr = np.array(devs).reshape(data, model_parallel)
-    return Mesh(arr, axis_names)
+    return Mesh(arr, axis_names,
+                axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def reshard(tree, logical_tree, new_mesh: Mesh,

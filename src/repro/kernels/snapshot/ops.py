@@ -19,11 +19,13 @@ _ref_jit = jax.jit(snapshot_ref, static_argnames=("with_hist",))
 
 
 def _block_rows_for(rows: int) -> int:
-    """Largest power-of-two tile height <= 512 that divides ``rows``."""
-    for br in (512, 256, 128, 64, 32, 16, 8, 4, 2, 1):
+    """Largest power-of-two tile height in 8..512 that divides ``rows``, or
+    the whole chunk: a block's height must be a multiple of the 8-row
+    sublane tile or the array's own."""
+    for br in (512, 256, 128, 64, 32, 16, 8):
         if rows % br == 0:
             return br
-    return 1
+    return rows
 
 
 def snapshot_chunks(
@@ -31,14 +33,19 @@ def snapshot_chunks(
     with_hist: bool = True, use_pallas: bool = None, interpret: bool = False,
 ) -> jnp.ndarray:
     """Fused per-chunk ``[s1, s2, dirty, hist…]`` of a (n_chunks, wpc) uint32
-    matrix — Pallas on TPU when the word grid is lane-aligned, the jitted
-    oracle otherwise.  The result stays on device; callers slice the digest
-    columns off as the next snapshot's ``prev_digests`` without a transfer.
+    matrix — the Pallas kernel on TPU, the jitted oracle elsewhere.  The
+    kernel needs whole 128-word lanes per chunk and raises otherwise.  The
+    result stays on device; callers slice the digest columns off as the next
+    snapshot's ``prev_digests`` without a transfer.
     """
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     wpc = words2.shape[1]
-    if use_pallas and wpc and wpc % _LANES == 0:
+    if use_pallas:
+        if wpc % _LANES:
+            raise ValueError(
+                f"snapshot kernel needs whole {_LANES}-word lanes per chunk, "
+                f"got {wpc} words")
         return snapshot_pallas(
             words2, prev_digests, block_rows=_block_rows_for(wpc // _LANES),
             with_hist=with_hist, interpret=interpret)

@@ -23,6 +23,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core import Box, Checkpoint
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import model as M
 from repro.train.steps import make_decode_step, make_prefill
 
@@ -138,6 +139,7 @@ def main() -> None:
                     help="serve /metrics + /healthz on this port (k8s "
                          "liveness probe; same as CRAFT_METRICS_PORT)")
     args = ap.parse_args()
+    setup_compile_cache()
     if args.metrics_port is not None:
         # Start the exporter up front so the replica answers its liveness
         # probe during prefill, before any Checkpoint commits.

@@ -33,6 +33,8 @@ from repro.core import Box, Checkpoint
 from repro.core import metrics as craft_metrics
 from repro.core.aft import aft_zone
 from repro.data.pipeline import DataCursor, SyntheticTokens
+from repro.launch.compile_cache import setup_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.models.common import ModelConfig
 from repro.optim.adamw import OptimConfig, adamw_init
@@ -47,6 +49,8 @@ log = logging.getLogger("craft.train")
 class TrainConfig:
     arch: str = "h2o-danube-1.8b"
     tiny: bool = True
+    preset: Optional[str] = None         # named preset of the arch, e.g.
+    #                                      "one_chip" (takes precedence)
     steps: int = 50
     global_batch: int = 8
     seq_len: int = 64
@@ -95,16 +99,21 @@ def init_state(cfg: ModelConfig, ocfg: OptimConfig, mesh, rules, seed: int):
 
 def run(tc: TrainConfig, comm=None, mesh=None,
         on_step: Optional[Callable[[int, Dict], None]] = None,
-        env=None) -> Dict:
-    """Train; returns {"losses": [...], "final_step": int, "stats": {...}}.
+        env=None,
+        on_start: Optional[Callable[[int, Dict], None]] = None) -> Dict:
+    """Train; returns {"losses": [...], "step_times": [...], "start_step",
+    "final_step", "state": {"params", "opt"}, "stats": {...}}.
 
-    With ``comm`` (an FTComm), the whole loop runs inside an AFT zone: the
-    checkpoint is (re)opened inside the zone body (paper Listing 9) so every
-    recovery re-reads the latest consistent version.
+    ``on_start(step, state)`` runs once before the first step, after any
+    restore, with the step the loop starts from and the live state.  The
+    default mesh is data-parallel over every device.  With ``comm`` (an
+    FTComm), the whole loop runs inside an AFT zone: the checkpoint is
+    (re)opened inside the zone body (paper Listing 9) so every recovery
+    re-reads the latest consistent version.
     """
-    cfg = get_config(tc.arch, tiny=tc.tiny)
+    cfg = get_config(tc.arch, tiny=tc.tiny, preset=tc.preset)
     if mesh is None:
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh((jax.device_count(),), ("data",))
     rules = _mesh_rules(mesh, tc.sequence_parallel)
     ocfg = OptimConfig(lr=tc.lr, master_fp32=False, warmup_steps=5,
                        total_steps=max(tc.steps, 10))
@@ -119,9 +128,11 @@ def run(tc: TrainConfig, comm=None, mesh=None,
     del shard, n_shards
 
     def body(comm_inner):
-        params, opt_state, pspecs, ospecs = init_state(
-            cfg, ocfg, mesh, rules, tc.seed)
+        params, opt_state, _, _ = init_state(cfg, ocfg, mesh, rules, tc.seed)
         state_box = Box({"params": params, "opt": opt_state})
+        # the box holds the only reference, so a restore that replaces the
+        # state frees the initial one instead of keeping both on the device
+        del params, opt_state
         step_box = Box(0)
         cursor = DataCursor(0)
 
@@ -131,9 +142,13 @@ def run(tc: TrainConfig, comm=None, mesh=None,
         cp.add("cursor", FuncBox(cursor))
         cp.commit()
         cp.restart_if_needed()
+        start_step = step_box.value
+        if on_start is not None:
+            on_start(start_step, state_box.value)
 
         jit_step = jax.jit(step_fn, donate_argnums=(0, 1))
         losses: List[float] = []
+        step_times: List[float] = []
         timer = StepTimer()
         t0 = time.perf_counter()
         try:
@@ -159,7 +174,8 @@ def run(tc: TrainConfig, comm=None, mesh=None,
                 losses.append(loss)
                 # compute-only step time (checkpoint writes excluded) feeds
                 # the scheduler's rework model and the result stats
-                timer.observe(time.perf_counter() - step_t0)
+                step_times.append(time.perf_counter() - step_t0)
+                timer.observe(step_times[-1])
                 if cp.policy is not None and timer.last is not None:
                     cp.policy.observe_step_seconds(timer.last)
                 # live telemetry: step cadence + loss on the scoreboard
@@ -186,7 +202,10 @@ def run(tc: TrainConfig, comm=None, mesh=None,
             cp.wait()
             return {
                 "losses": losses,
+                "step_times": step_times,
+                "start_step": start_step,
                 "final_step": step_box.value,
+                "state": state_box.value,
                 "wall_s": time.perf_counter() - t0,
                 "step_seconds": timer.ewma,
                 "stats": dict(cp.stats),
@@ -238,15 +257,18 @@ def main() -> None:
     ap.add_argument("--arch", default="h2o-danube-1.8b")
     ap.add_argument("--tiny", action="store_true", default=True)
     ap.add_argument("--full", dest="tiny", action="store_false")
+    ap.add_argument("--preset", default=None,
+                    help="named preset of the arch, e.g. one_chip")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--global-batch", type=int, default=8)
     ap.add_argument("--seq-len", type=int, default=64)
     ap.add_argument("--cp-freq", type=int, default=10)
     args = ap.parse_args()
-    tc = TrainConfig(arch=args.arch, tiny=args.tiny, steps=args.steps,
-                     global_batch=args.global_batch, seq_len=args.seq_len,
-                     cp_freq=args.cp_freq)
+    tc = TrainConfig(arch=args.arch, tiny=args.tiny, preset=args.preset,
+                     steps=args.steps, global_batch=args.global_batch,
+                     seq_len=args.seq_len, cp_freq=args.cp_freq)
     logging.basicConfig(level=logging.INFO)
+    setup_compile_cache()
     out = run(tc, on_step=lambda s, m: print(
         f"step {s:4d} loss {float(m['loss']):.4f} "
         f"gnorm {float(m['grad_norm']):.3f}"))

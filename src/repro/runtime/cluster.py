@@ -12,6 +12,13 @@
 The worker function must be a module-level (picklable) callable — workers
 are spawned with the ``spawn`` start method so JAX state never crosses a
 fork.
+
+Workers must not hold the accelerator.  A TPU chip belongs to one process
+at a time, so on a TPU host every worker that imports JAX would race for
+it.  Workers therefore run JAX on the CPU: ``JAX_PLATFORMS=cpu`` is part of
+the environment they are given (``env_overrides`` may name another
+platform explicitly).  Start no ``Cluster`` from a process that has touched
+JAX on the chip, and drive the chip from one process of its own.
 """
 from __future__ import annotations
 
@@ -42,6 +49,7 @@ class Cluster:
         self.ppn = max(1, procs_per_node)
         self.recovery_policy = recovery_policy.upper()
         self.env_overrides = dict(env_overrides or {})
+        self.env_overrides.setdefault("JAX_PLATFORMS", "cpu")
         self.env_overrides.setdefault(
             "CRAFT_COMM_RECOVERY_POLICY", self.recovery_policy
         )

@@ -40,6 +40,29 @@ class TestSnapshotKernel:
                               with_hist=with_hist, interpret=True)
         np.testing.assert_array_equal(np.asarray(ker), np.asarray(ref))
 
+    @pytest.mark.parametrize("rows", [3, 24, 40])
+    @pytest.mark.parametrize("with_hist", [True, False])
+    def test_ops_block_choice_matches_ref(self, rng, rows, with_hist):
+        """The block height the ops pick (whole chunk when 8 does not
+        divide it, else a multiple of 8 over several row blocks) gives the
+        oracle's result bit for bit."""
+        words = jnp.asarray(
+            rng.integers(0, 2**32, size=(2, rows * 128), dtype=np.uint32))
+        prev = jnp.asarray(rng.integers(0, 2**32, (2, 2), dtype=np.uint32))
+        ker = snapshot_ops.snapshot_chunks(
+            words, prev, with_hist=with_hist, use_pallas=True,
+            interpret=True)
+        ref = snapshot_ref(words, prev, with_hist=with_hist)
+        np.testing.assert_array_equal(np.asarray(ker), np.asarray(ref))
+
+    def test_kernel_path_rejects_partial_lanes(self):
+        """The kernel path never hands a lane-misaligned grid to the oracle
+        behind the caller's back."""
+        with pytest.raises(ValueError, match="lanes"):
+            snapshot_ops.snapshot_chunks(
+                jnp.zeros((2, 100), jnp.uint32), jnp.zeros((2, 2), jnp.uint32),
+                use_pallas=True)
+
     def test_digest_columns_match_checksum_kernel(self, rng):
         data = rng.bytes(4096)
         words = jnp.asarray(
@@ -123,9 +146,10 @@ class TestDeviceSnapshotter:
         _host_equals(host, a)
         assert meta is not None and meta["dirty"] is None
 
-    def test_bfloat16(self):
+    @pytest.mark.parametrize("staged", [None, True])
+    def test_bfloat16(self, staged):
         a = jnp.arange(512, dtype=jnp.bfloat16)
-        host, meta = DeviceSnapshotter(256).snapshot("k", a)
+        host, meta = DeviceSnapshotter(256, staged=staged).snapshot("k", a)
         _host_equals(host, a)
         assert meta is not None
 
@@ -176,6 +200,13 @@ class TestDeviceSnapshotter:
             host, meta = snap.snapshot("k", arr)
             assert meta is None
             _host_equals(host, arr)
+
+    def test_tpu_misaligned_chunk_grid_names_the_knob(self, monkeypatch):
+        """On TPU a multi-chunk grid whose chunks are not whole lanes raises
+        and names CRAFT_CHUNK_BYTES (no silent switch to the oracle)."""
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(ValueError, match="CRAFT_CHUNK_BYTES=256"):
+            DeviceSnapshotter(256).snapshot("k", jnp.zeros(512, jnp.float32))
 
     def test_reshape_resets_to_full_write(self, rng):
         snap = DeviceSnapshotter(256)

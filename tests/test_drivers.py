@@ -56,6 +56,30 @@ class TestTrainDriver:
         np.testing.assert_allclose(
             resumed["losses"][-5:], ref["losses"][-5:], rtol=1e-4)
 
+    def test_restore_frees_initial_state(self, tmp_path):
+        """After a restore only the restored state is live on the device:
+        the freshly initialized one it replaced is not kept alongside."""
+        import gc
+
+        import jax
+
+        env = _env(tmp_path)
+        kw = dict(arch=ARCH, steps=2, cp_freq=2, global_batch=4, seq_len=32)
+        first = train_mod.run(train_mod.TrainConfig(**kw), env=env)
+        state_bytes = sum(x.nbytes for x in
+                          jax.tree_util.tree_leaves(first.pop("state")))
+        del first
+        seen = {}
+
+        def on_start(step, state):
+            gc.collect()
+            seen["step"] = step
+            seen["live"] = sum(a.nbytes for a in jax.live_arrays())
+
+        train_mod.run(train_mod.TrainConfig(**kw), env=env, on_start=on_start)
+        assert seen["step"] == 2
+        assert seen["live"] < 1.5 * state_bytes
+
     def test_aft_zone_with_sim_comm(self, tmp_path):
         """Injected rank failure mid-training; AFT zone recovers and the
         final state matches the no-failure run."""

@@ -3,6 +3,8 @@
 The analyzer's whole point is fixing XLA cost-analysis' count-scan-body-once
 behavior, so the key test compiles a scan and checks the ×N multiplication.
 """
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -128,8 +130,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 import sys
 sys.path.insert(0, "src")
 from repro.analysis import roofline as R
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((8,), ("d",))
+mesh = make_mesh((8,), ("d",))
 xsh = NamedSharding(mesh, P("d", None))
 x = jax.ShapeDtypeStruct((1024, 64), jnp.float32, sharding=xsh)
 rep = R.analyze(jax.jit(
@@ -139,6 +142,7 @@ assert rep.collective_bytes > 0, rep.as_dict()
 assert "all-reduce" in rep.collective_by_kind
 print("OK")
 """)
-        r = subprocess.run([sys.executable, str(script)], cwd="/root/repo",
+        r = subprocess.run([sys.executable, str(script)],
+                           cwd=Path(__file__).resolve().parents[1],
                            capture_output=True, text=True, timeout=300)
         assert "OK" in r.stdout, r.stderr[-2000:]

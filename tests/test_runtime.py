@@ -87,3 +87,12 @@ def test_aft_zone_survives_kill9():
     cluster.kill(0)                      # even rank 0 may die
     results = cluster.join(timeout=120)
     assert set(results.values()) == {3}
+
+
+@pytest.mark.parametrize("given,expect", [(None, "cpu"), ("tpu", "tpu")])
+def test_workers_stay_off_the_accelerator(given, expect):
+    """Workers run JAX on the CPU unless the caller names a platform: a TPU
+    chip belongs to one process, never to every worker at once."""
+    overrides = {"JAX_PLATFORMS": given} if given else None
+    with Cluster(n_procs=1, env_overrides=overrides) as cluster:
+        assert cluster.env_overrides["JAX_PLATFORMS"] == expect

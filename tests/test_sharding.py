@@ -1,17 +1,20 @@
 """Logical sharding rules + elastic restore (cross-mesh checkpoint)."""
 import subprocess
 import sys
+from pathlib import Path
 
-import jax
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
 from repro.sharding.logical import DEFAULT_RULES, LogicalRules
+
+REPO = Path(__file__).resolve().parents[1]
 
 
 @pytest.fixture(scope="module")
 def mesh1():
-    return jax.make_mesh((1,), ("data",))
+    return make_mesh((1,), ("data",))
 
 
 class TestRules:
@@ -75,10 +78,11 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import Box, Checkpoint
 from repro.core.elastic import shrink_mesh, reshard
 from repro.core.env import CraftEnv
+from repro.launch.mesh import make_mesh
 
 env = CraftEnv.capture({{"CRAFT_CP_PATH": r"{tmp_path}/pfs",
                          "CRAFT_USE_SCR": "0"}})
-mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+mesh_a = make_mesh((4, 2), ("data", "model"))
 x = jnp.arange(64.0).reshape(8, 8)
 xa = jax.device_put(x, NamedSharding(mesh_a, P("data", "model")))
 box = Box(xa)
@@ -104,7 +108,7 @@ y, _ = reshard({{"w": box2.value}}, {{"w": ("batch", "embed")}}, mesh_b)
 np.testing.assert_array_equal(np.asarray(y["w"]), np.asarray(x))
 print("OK")
 """)
-        r = subprocess.run([sys.executable, str(script)], cwd="/root/repo",
+        r = subprocess.run([sys.executable, str(script)], cwd=REPO,
                            capture_output=True, text=True, timeout=300)
         assert "OK" in r.stdout, (r.stdout[-800:], r.stderr[-2000:])
 
@@ -125,8 +129,9 @@ import jax
 from repro.configs import ShapeSpec
 from repro.launch.specs import build_step
 from repro.analysis import roofline as R
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 for kind, name in (("train", "tiny_train"), ("prefill", "tiny_prefill"),
                    ("decode", "tiny_decode")):
     shape = ShapeSpec(name, seq_len=64, global_batch=4, kind=kind)
@@ -138,6 +143,6 @@ for kind, name in (("train", "tiny_train"), ("prefill", "tiny_prefill"),
     assert mem.temp_size_in_bytes > 0
 print("OK")
 """)
-        r = subprocess.run([sys.executable, str(script)], cwd="/root/repo",
+        r = subprocess.run([sys.executable, str(script)], cwd=REPO,
                            capture_output=True, text=True, timeout=560)
         assert "OK" in r.stdout, (r.stdout[-800:], r.stderr[-2500:])
