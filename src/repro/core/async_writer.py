@@ -134,7 +134,6 @@ class AsyncWriter:
                     self.stats["job_seconds"] += dt
                     self.stats["last_job_seconds"] = dt
                     self._cv.notify_all()
-                metrics.observe("async_job_seconds", dt)
                 metrics.set_gauge("async_pending", pending)
 
     def _pool_loop(self) -> None:
@@ -168,12 +167,9 @@ class AsyncWriter:
 
     def wait(self) -> None:
         """Block until all submitted jobs finished; re-raise writer errors."""
-        t0 = time.perf_counter() if metrics.REGISTRY.enabled else 0.0
         with self._cv:
             while self._pending > 0:
                 self._cv.wait()
-        if t0:
-            metrics.observe("async_fence_seconds", time.perf_counter() - t0)
         self._raise_pending_error()
 
     # -- stall watchdog --------------------------------------------------------

@@ -326,18 +326,17 @@ class Checkpoint:
                 label=f"{self.name} v-{version}")
         elif self.env.write_async:
             # copy-based: snapshot inline (cheap D2H), IO on writer thread.
-            self._update_all()
+            self._update_all(version)
             self._writer.submit(
                 lambda v=version, d=decision: self._write_version(v, d),
                 label=f"{self.name} v-{version}")
         else:
             # synchronous: IO inline — the writer (if any) only serves
             # run_parallel fanout of per-array/per-chunk jobs.
-            self._update_all()
+            self._update_all(version)
             self._write_version(version, decision)
         self._version = version
         self._last_write_t = self._clock()
-        metrics.set_gauge("cp_version", version, cp=self.name)
         self._policy.record_written(decision, version)
         if decision.reason == "preempt":
             self.stats.inc("preempt_flushes")
@@ -403,15 +402,20 @@ class Checkpoint:
             self._writer.check_stall(self.env.io_deadline_s)
         return d
 
-    def _update_all(self) -> None:
-        for item in self._map.values():
-            item.update()
+    def _update_all(self, version: int) -> None:
+        with trace.TRACER.span("cp.update", version=version):
+            for item in self._map.values():
+                item.update()
 
     def _snapshot_and_write(self, version: int, decision=None) -> None:
-        self._update_all()
+        self._update_all(version)
         self._write_version(version, decision)
 
     def _write_version(self, version: int, decision=None) -> None:
+        with trace.TRACER.span("cp.write_version", version=version):
+            self._write_tiers(version, decision)
+
+    def _write_tiers(self, version: int, decision=None) -> None:
         """Write ``version`` to the scheduled tiers, degrading around faults.
 
         Per tier: an open circuit breaker skips the tier outright; a write
@@ -549,14 +553,16 @@ class Checkpoint:
         stay hung; it can only abort its own staging dir, never publish)
         instead of wedging the sequencer or a sync commit.  Returns the
         write's codec ``io_stats`` dict."""
-        deadline = self.env.io_deadline_s
-        if deadline > 0:
-            from repro.core.health import call_with_deadline
+        with trace.TRACER.span("tier.write", slot=slot):
+            deadline = self.env.io_deadline_s
+            if deadline > 0:
+                from repro.core.health import call_with_deadline
 
-            return call_with_deadline(
-                lambda: self._write_to_store(store, version, slot, force_full),
-                deadline, name=f"{self.name} {slot} v-{version}")
-        return self._write_to_store(store, version, slot, force_full)
+                return call_with_deadline(trace.carry(
+                    lambda: self._write_to_store(store, version, slot,
+                                                 force_full)),
+                    deadline, name=f"{self.name} {slot} v-{version}")
+            return self._write_to_store(store, version, slot, force_full)
 
     def _delta_plan(self, slot: str, force_full: bool = False) -> Optional[dict]:
         """Delta state to diff against for this write, or None for a full
@@ -636,18 +642,19 @@ class Checkpoint:
                     {"version": version, "deps": sorted(deps)},
                     ctx=ctx,
                 )
-            store.publish(
-                staged,
-                version,
-                extra_meta={
-                    "keys": sorted(self._map),
-                    "codec": self.env.codec_version,
-                    # rank 0's view of the per-file digest manifest; restore
-                    # checks these files exist before reading the version
-                    "checksums": checksums,
-                    **({"delta_deps": sorted(deps)} if delta_on else {}),
-                },
-            )
+            with trace.TRACER.span("tier.publish"):
+                store.publish(
+                    staged,
+                    version,
+                    extra_meta={
+                        "keys": sorted(self._map),
+                        "codec": self.env.codec_version,
+                        # rank 0's view of the per-file digest manifest;
+                        # restore checks these files exist before reading
+                        "checksums": checksums,
+                        **({"delta_deps": sorted(deps)} if delta_on else {}),
+                    },
+                )
         except BaseException as exc:
             from repro.core.chaos import ChaosCrash
 
@@ -666,11 +673,6 @@ class Checkpoint:
         self.stats.inc("delta_chunks_total", io_stats.get("chunks", 0))
         self.stats.inc("delta_chunks_skipped", io_stats.get("ref_chunks", 0))
         self.stats.inc("retries", io_stats.get("retries", 0))
-        # per-tier codec series (the delta hit rate is ref_chunks / chunks)
-        metrics.inc("tier_phys_bytes", io_stats.get("bytes", 0), slot=slot)
-        metrics.inc("tier_chunks", io_stats.get("chunks", 0), slot=slot)
-        metrics.inc("tier_ref_chunks", io_stats.get("ref_chunks", 0),
-                    slot=slot)
         return io_stats
 
     def _run_item_write(self, item, sub: Path, ctx: IOContext,
@@ -796,26 +798,33 @@ class Checkpoint:
     def _read_from_store(self, store, slot, label, version, base_ctx):
         """One tier's restore attempt; returns None on success, else the
         error string to report (the caller may repair and retry once)."""
+        with trace.TRACER.span("cp.restore", version=version, slot=slot):
+            return self._read_tier(store, slot, label, version, base_ctx)
+
+    def _read_tier(self, store, slot, label, version, base_ctx):
         ts = time.perf_counter()
-        try:
-            # may trigger replica / partner / XOR / RS recovery; an
-            # unrecoverable tier falls through to the next one (the
-            # base-class materialize is a plain local-dir check)
-            vdir = store.materialize(version)
-        except CheckpointError as exc:
-            return f"{label}: {exc}"
-        if vdir is None or not Path(vdir).is_dir():
-            return f"{label}: version v-{version} not present"
-        missing = self._manifest_missing(store, Path(vdir), version)
-        if missing:
-            return f"{label}: v-{version} incomplete, missing {missing[:3]}"
-        # Delta chain: every base version the v2 refs resolve through
-        # must be materialized on this same tier before reading; a hole
-        # in the chain fails this tier explicitly (no decode crash).
-        try:
-            base_dirs = self._materialize_chain(store, Path(vdir), version)
-        except CheckpointError as exc:
-            return f"{label}: v-{version} {exc}"
+        with trace.TRACER.span("restore.materialize"):
+            try:
+                # may trigger replica / partner / XOR / RS recovery; an
+                # unrecoverable tier falls through to the next one (the
+                # base-class materialize is a plain local-dir check)
+                vdir = store.materialize(version)
+            except CheckpointError as exc:
+                return f"{label}: {exc}"
+            if vdir is None or not Path(vdir).is_dir():
+                return f"{label}: version v-{version} not present"
+            missing = self._manifest_missing(store, Path(vdir), version)
+            if missing:
+                return (f"{label}: v-{version} incomplete, "
+                        f"missing {missing[:3]}")
+            # Delta chain: every base version the v2 refs resolve through
+            # must be materialized on this same tier before reading; a hole
+            # in the chain fails this tier explicitly (no decode crash).
+            try:
+                base_dirs = self._materialize_chain(store, Path(vdir),
+                                                    version)
+            except CheckpointError as exc:
+                return f"{label}: v-{version} {exc}"
         overrides = dict(store.read_ctx_overrides(version))
         overrides.setdefault("rel_root", Path(vdir))
         overrides.setdefault("chaos", getattr(store, "chaos_scope", None))

@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.cpbase import CheckpointError, CpBase, IOContext
-from repro.core import reshard, storage, tiers
+from repro.core import reshard, storage, tiers, trace
 from repro.core.device_snapshot import DeviceSnapshotter
 
 T = TypeVar("T")
@@ -269,16 +269,18 @@ def _read_global_leaf(ctx: IOContext, gshape, dtype, sources, live,
         for ext, path, _root in exts:
             arr = storage.read_array(path, ctx)
             idx = tuple(slice(lo, hi) for lo, hi in ext)
-            _assign_shard(out, idx, arr)
-            if filled is not None:
-                filled[idx] = True
+            with trace.TRACER.span("restore.assemble", nbytes=arr.nbytes):
+                _assign_shard(out, idx, arr)
+                if filled is not None:
+                    filled[idx] = True
         if filled is not None and not filled.all():
             raise CheckpointError(
                 f"incomplete shard coverage under {where} "
                 f"({int(filled.sum())}/{filled.size} elements)"
             )
         if live_is_jax:
-            return jax.device_put(out, live.sharding)
+            with trace.TRACER.span("restore.place", nbytes=out.nbytes):
+                return jax.device_put(out, live.sharding)
         return out
     rdr_cache: dict = {}
 
@@ -292,7 +294,9 @@ def _read_global_leaf(ctx: IOContext, gshape, dtype, sources, live,
     srcs = [(e, (str(p), p, root)) for e, p, root in exts]
     blocks = {}
     for e in (dst_exts if dst_exts is not None else [full_ext]):
-        block, covered = reshard.assemble_extent(e, dtype, srcs, open_reader)
+        with trace.TRACER.span("restore.assemble"):
+            block, covered = reshard.assemble_extent(e, dtype, srcs,
+                                                     open_reader)
         if covered is not None and not covered.all():
             raise CheckpointError(
                 f"incomplete shard coverage for extent {e} under {where} "
@@ -300,13 +304,14 @@ def _read_global_leaf(ctx: IOContext, gshape, dtype, sources, live,
             )
         blocks[e] = block
     if live_is_jax:
-        shard_arrs = [
-            jax.device_put(
-                blocks[reshard.resolve_index(s.index, gshape)], s.device)
-            for s in live.addressable_shards
-        ]
-        return jax.make_array_from_single_device_arrays(
-            gshape, live.sharding, shard_arrs)
+        with trace.TRACER.span("restore.place"):
+            shard_arrs = [
+                jax.device_put(
+                    blocks[reshard.resolve_index(s.index, gshape)], s.device)
+                for s in live.addressable_shards
+            ]
+            return jax.make_array_from_single_device_arrays(
+                gshape, live.sharding, shard_arrs)
     return blocks[full_ext]
 
 
@@ -351,7 +356,8 @@ class JaxArrayCp(CpBase):
         else:
             # Device→host snapshot of every addressable shard — one batched
             # transfer instead of a blocking per-shard np.asarray.
-            hosts = jax.device_get([s.data for s in shards])
+            with trace.TRACER.span("snapshot.device_get", shards=len(shards)):
+                hosts = jax.device_get([s.data for s in shards])
             self._buf = [
                 (s.index, np.asarray(h), None)
                 for s, h in zip(shards, hosts)
@@ -451,7 +457,9 @@ class PytreeCp(CpBase):
                 buf.append({"kind": "pod", "data": leaf})
         if jax_shards:
             # One batched device→host transfer for every jax leaf's shards.
-            hosts = jax.device_get([s.data for _, s in jax_shards])
+            with trace.TRACER.span("snapshot.device_get",
+                                   shards=len(jax_shards)):
+                hosts = jax.device_get([s.data for _, s in jax_shards])
             for (item, s), h in zip(jax_shards, hosts):
                 item["shards"].append((s.index, np.asarray(h), None))
         self._buf = buf

@@ -167,10 +167,7 @@ class IOContext:
 
     def record_retry(self) -> None:
         """Account one transient-error retry (surfaces in
-        ``Checkpoint.stats['retries']`` and the ``io_retries`` counter)."""
-        from repro.core import metrics
-
-        metrics.inc("io_retries")
+        ``Checkpoint.stats['retries']``)."""
         if self.io_stats is not None:
             with self._lock:
                 self.io_stats["retries"] = self.io_stats.get("retries", 0) + 1

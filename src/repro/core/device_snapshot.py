@@ -46,7 +46,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core import metrics
+from repro.core import metrics, trace
 from repro.kernels.snapshot import ops as snapshot_ops
 
 _LANES = 128
@@ -165,6 +165,11 @@ class DeviceSnapshotter:
         is the device-produced chunk metadata for
         ``IOContext.record_device_meta`` — or ``None`` when the shard took
         the plain host path."""
+        with trace.TRACER.span("snapshot.leaf", leaf=key) as sp:
+            return self._snapshot(key, arr, sp)
+
+    def _snapshot(self, key, arr: jax.Array, sp
+                  ) -> Tuple[np.ndarray, Optional[dict]]:
         dtype = np.dtype(arr.dtype)
         nbytes = int(arr.size) * dtype.itemsize
         if (nbytes == 0 or nbytes % 4 or self.chunk_bytes % 4
@@ -173,6 +178,7 @@ class DeviceSnapshotter:
             return np.asarray(arr), None
         shape = tuple(arr.shape)
         n_chunks, wpc = self._grid(nbytes)
+        sp.set(nbytes=nbytes, n_chunks=n_chunks)
 
         st = self._state.get(key)
         if st is not None and (st.shape != shape or st.dtype != dtype
@@ -209,13 +215,18 @@ class DeviceSnapshotter:
                     else jnp.zeros((n_chunks, 2), jnp.uint32))
             kw = dict(n_chunks=n_chunks, wpc=wpc, with_hist=self.with_hist,
                       use_pallas=use_pallas)
-            if donate and st.words is not None:
-                words2, meta_dev = _fused_donate(arr, prev, st.words, **kw)
-            else:
-                words2, meta_dev = _fused(arr, prev, **kw)
+            donated = donate and st.words is not None
+            sp.set(donate=donated)
+            with trace.TRACER.span("snapshot.dispatch"):
+                if donated:
+                    words2, meta_dev = _fused_donate(arr, prev, st.words,
+                                                     **kw)
+                else:
+                    words2, meta_dev = _fused(arr, prev, **kw)
             st.prev_digests = meta_dev[:, :2]
             st.words = words2 if donate else None
-            meta_host = np.asarray(meta_dev)
+            with trace.TRACER.span("snapshot.wait"):
+                meta_host = np.asarray(meta_dev)
             cur_dig = meta_host[:, :2]
             # Patch this round's mirror: fetch exactly the chunks whose
             # digest changed since the mirror was last current (a superset
@@ -231,10 +242,13 @@ class DeviceSnapshotter:
             else:
                 rows = np.flatnonzero(
                     (cur_dig != st.mirror_digs[mi]).any(axis=1))
-            if rows.size == n_chunks:
-                mirror[...] = np.asarray(words2)         # one full transfer
-            elif rows.size:
-                mirror[rows] = np.asarray(words2[rows])  # gather, dirty only
+            sp.set(rows=int(rows.size))
+            with trace.TRACER.span("snapshot.d2h",
+                                   nbytes=int(rows.size) * wpc * 4):
+                if rows.size == n_chunks:
+                    mirror[...] = np.asarray(words2)     # one full transfer
+                elif rows.size:
+                    mirror[rows] = np.asarray(words2[rows])  # dirty only
             st.mirror_digs[mi] = cur_dig.copy()
             host = (mirror.reshape(-1).view(np.uint8)[:nbytes]
                     .view(dtype).reshape(shape))
@@ -255,13 +269,6 @@ class DeviceSnapshotter:
                       else meta_host[:, 2].astype(bool).tolist()),
             "entropy_bits": entropy,
         }
-        if metrics.REGISTRY.enabled:   # keep the unset path numpy-free
-            if meta["dirty"] is not None:
-                metrics.set_gauge(
-                    "snapshot_dirty_fraction",
-                    sum(meta["dirty"]) / max(1, n_chunks))
-            if staged:
-                metrics.inc("snapshot_d2h_bytes", int(rows.size) * wpc * 4)
-                metrics.inc("snapshot_d2h_bytes_saved",
-                            (n_chunks - int(rows.size)) * wpc * 4)
+        if staged:
+            metrics.inc("snapshot_d2h_bytes", int(rows.size) * wpc * 4)
         return host, meta

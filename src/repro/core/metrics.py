@@ -11,8 +11,8 @@ Design mirrors ``trace.py`` exactly:
 * a module-global :data:`REGISTRY` that stays the no-op
   :class:`_NullRegistry` until :func:`install` — when ``CRAFT_METRICS`` is
   unset every hook is a single dynamic call that immediately returns (no
-  branching, no locking, no string formatting; ``benchmarks/cr_overhead.py
-  metrics_overhead`` keeps the armed-vs-off delta on the scoreboard);
+  branching, no locking, no string formatting; ``PERF.md`` gives what the
+  instrumentation costs on the chip);
 * process-global, because one process may run several ``Checkpoint``
   objects plus an async writer plus a scrubber thread, and the exporter
   needs one coherent scrape of all of them;

@@ -42,11 +42,10 @@ from __future__ import annotations
 
 import json
 import shutil
-import time as _time
 from pathlib import Path
 from typing import Dict, List, Optional
 
-from repro.core import erasure, metrics, storage, tiers
+from repro.core import erasure, storage, tiers
 from repro.core.cpbase import CheckpointError
 from repro.core.tiers import StorageTier
 from repro.kernels.xor_parity import ops as xor_ops
@@ -135,7 +134,6 @@ class NodeStore(StorageTier):
         self._local.abort(staged)
 
     def publish(self, staged: Path, version: int, extra_meta: Optional[dict] = None) -> None:
-        t0 = _time.perf_counter()
         self._chaos_check("publish", path=staged)
         self.comm.barrier()          # all ranks wrote their node-local files
         if self.is_leader:
@@ -152,8 +150,6 @@ class NodeStore(StorageTier):
                 self._chaos_check("replicate", path=staged)
                 erasure.publish_rs(self, version)
         self.comm.barrier()          # redundancy data in place
-        metrics.observe("publish_seconds", _time.perf_counter() - t0,
-                        tier="node")
 
     def _publish_partner(self, version: int) -> None:
         src = self._local.version_dir(version)

@@ -467,7 +467,6 @@ class CheckpointPolicy:
         trace.TRACER.emit("degraded", slot=slot)
         self._degraded.add(slot)
         self._last_write_t[slot] = -math.inf
-        metrics.set_gauge("policy_degraded_slots", len(self._degraded))
 
     def note_tier_written(self, slot: str) -> None:
         """A write actually landed on ``slot`` (called by ``Checkpoint`` on
@@ -475,7 +474,6 @@ class CheckpointPolicy:
         :meth:`record_written` which only sees the *scheduled* tier set)."""
         self._degraded.discard(slot)
         self._deferred.discard(slot)
-        metrics.set_gauge("policy_degraded_slots", len(self._degraded))
         if slot in self._last_write_t:
             self._last_write_t[slot] = self._clock()
 

@@ -66,7 +66,7 @@ try:  # optional transparent compression (beyond-paper extension)
 except ImportError:  # pragma: no cover
     _zstd = None
 
-from repro.core import tiers
+from repro.core import tiers, trace
 from repro.core.cpbase import CheckpointError, IOContext
 from repro.core.tiers import StorageTier, fsync_dir  # re-export (legacy API)
 
@@ -176,7 +176,7 @@ def run_jobs(jobs, ctx: IOContext) -> list:
     """Run independent IO jobs through ``ctx.fanout`` when available, else
     inline — the single dispatch point for per-array and per-chunk fanout."""
     if ctx.fanout is not None and len(jobs) > 1:
-        return ctx.fanout(jobs)
+        return ctx.fanout([trace.carry(job) for job in jobs])
     return [job() for job in jobs]
 
 
@@ -231,7 +231,8 @@ def _atomic_write_file(path: Path, parts, ctx: IOContext) -> None:
             tmp.unlink(missing_ok=True)
             raise
 
-    _retrying(attempt, ctx)
+    with trace.TRACER.span("file.write", file=path.name, nbytes=total):
+        _retrying(attempt, ctx)
 
 
 # --------------------------------------------------------------------------
@@ -286,57 +287,59 @@ def _write_array_v1(path: Path, arr: np.ndarray, ctx: IOContext) -> None:
     want_digest = ctx.checksum != "none"
     n = flat.size
     offsets = list(range(0, n, chunk_bytes)) if n else []
-    dm = ctx.lookup_device_meta(
-        _manifest_name(path, ctx), n, chunk_bytes, len(offsets))
+    with trace.TRACER.span("codec.encode", file=path.name, nbytes=int(n),
+                           n_chunks=len(offsets)):
+        dm = ctx.lookup_device_meta(
+            _manifest_name(path, ctx), n, chunk_bytes, len(offsets))
 
-    # Uncompressed chunks are digested over their raw bytes: the device
-    # snapshot's fused digests serve directly when present, else the whole
-    # array goes through one batched kernel dispatch; compressed chunks are
-    # digested post-compression inside the fanout jobs.
-    if want_digest and compress != "zstd" and n:
-        raw_digests = (dm["rdigests"] if dm is not None
-                       else _digest_all_chunks(flat, chunk_bytes))
-    else:
-        raw_digests = []
-
-    def encode(i: int, off: int):
-        raw = flat[off: off + chunk_bytes]
-        if compress == "zstd" and _gate_allows_zstd(i, raw, ctx, dm):
-            # the compressor reads the buffer protocol directly — no
-            # tobytes() copy of the uncompressed chunk
-            stored = _compressor(ctx.zstd_level).compress(raw)
-            digest = _digest_chunk(stored) if want_digest else [0, 0]
-        elif compress == "zstd":
-            # gated: incompressible-looking chunk stored raw inside the
-            # zstd file; its stored-bytes digest is the raw digest
-            stored = memoryview(raw)
-            digest = ([int(d) for d in dm["rdigests"][i]] if dm is not None
-                      else _digest_chunk(raw)) if want_digest else [0, 0]
-            return stored, {"clen": len(stored), "ulen": int(raw.size),
-                            "digest": digest, "enc": "raw"}
+        # Uncompressed chunks are digested over their raw bytes: the device
+        # snapshot's fused digests serve directly when present, else the whole
+        # array goes through one batched kernel dispatch; compressed chunks are
+        # digested post-compression inside the fanout jobs.
+        if want_digest and compress != "zstd" and n:
+            raw_digests = (dm["rdigests"] if dm is not None
+                           else _digest_all_chunks(flat, chunk_bytes))
         else:
-            stored = memoryview(raw)
-            digest = ([int(d) for d in raw_digests[i]]
-                      if want_digest else [0, 0])
-        return stored, {"clen": len(stored), "ulen": int(raw.size),
-                        "digest": digest}
+            raw_digests = []
 
-    encoded = run_jobs(
-        [lambda i=i, off=off: encode(i, off)
-         for i, off in enumerate(offsets)], ctx)
-    chunks_meta = [meta for _, meta in encoded]
-    header = json.dumps(
-        {
-            "fmt": CODEC_V1,
-            "dtype": _dtype_to_name(arr.dtype),
-            "shape": shape,
-            "compress": compress,
-            "checksum": "fletcher" if want_digest else "none",
-            "chunk_bytes": chunk_bytes,
-            "nbytes": int(n),
-            "chunks": chunks_meta,
-        }
-    ).encode()
+        def encode(i: int, off: int):
+            raw = flat[off: off + chunk_bytes]
+            if compress == "zstd" and _gate_allows_zstd(i, raw, ctx, dm):
+                # the compressor reads the buffer protocol directly — no
+                # tobytes() copy of the uncompressed chunk
+                stored = _compressor(ctx.zstd_level).compress(raw)
+                digest = _digest_chunk(stored) if want_digest else [0, 0]
+            elif compress == "zstd":
+                # gated: incompressible-looking chunk stored raw inside the
+                # zstd file; its stored-bytes digest is the raw digest
+                stored = memoryview(raw)
+                digest = ([int(d) for d in dm["rdigests"][i]] if dm is not None
+                          else _digest_chunk(raw)) if want_digest else [0, 0]
+                return stored, {"clen": len(stored), "ulen": int(raw.size),
+                                "digest": digest, "enc": "raw"}
+            else:
+                stored = memoryview(raw)
+                digest = ([int(d) for d in raw_digests[i]]
+                          if want_digest else [0, 0])
+            return stored, {"clen": len(stored), "ulen": int(raw.size),
+                            "digest": digest}
+
+        encoded = run_jobs(
+            [lambda i=i, off=off: encode(i, off)
+             for i, off in enumerate(offsets)], ctx)
+        chunks_meta = [meta for _, meta in encoded]
+        header = json.dumps(
+            {
+                "fmt": CODEC_V1,
+                "dtype": _dtype_to_name(arr.dtype),
+                "shape": shape,
+                "compress": compress,
+                "checksum": "fletcher" if want_digest else "none",
+                "chunk_bytes": chunk_bytes,
+                "nbytes": int(n),
+                "chunks": chunks_meta,
+            }
+        ).encode()
     _atomic_write_file(
         path,
         [_MAGIC, len(header).to_bytes(8, "little"), header,
@@ -391,48 +394,51 @@ def _write_array_v2(path: Path, arr: np.ndarray, ctx: IOContext) -> None:
     # every chunk next to the data — consume those digests when the grid
     # matches; otherwise digest every raw chunk in one batched kernel
     # dispatch.  This is the whole per-version cost of a clean chunk.
-    dm = ctx.lookup_device_meta(rel, n, chunk_bytes, len(offsets))
-    raw_digests = (dm["rdigests"] if dm is not None
-                   else (_digest_all_chunks(flat, chunk_bytes) if n else []))
+    with trace.TRACER.span("codec.encode", file=path.name, nbytes=int(n),
+                           n_chunks=len(offsets)):
+        dm = ctx.lookup_device_meta(rel, n, chunk_bytes, len(offsets))
+        raw_digests = (
+            dm["rdigests"] if dm is not None
+            else (_digest_all_chunks(flat, chunk_bytes) if n else []))
 
-    def encode(i: int, off: int):
-        raw = flat[off: off + chunk_bytes]
-        rdigest = [int(d) for d in raw_digests[i]]
-        if prev is not None and list(prev["rdigests"][i]) == rdigest:
-            # clean chunk: reference the base version instead of re-writing
-            return None, {"ref": int(ctx.delta_base), "ulen": int(raw.size),
-                          "rdigest": rdigest}
-        if compress == "zstd" and _gate_allows_zstd(i, raw, ctx, dm):
-            stored = _compressor(ctx.zstd_level).compress(raw)
-            digest = _digest_chunk(stored)
-        elif compress == "zstd":
-            # gated raw chunk inside a zstd file: stored == raw bytes
-            stored = memoryview(raw)
+        def encode(i: int, off: int):
+            raw = flat[off: off + chunk_bytes]
+            rdigest = [int(d) for d in raw_digests[i]]
+            if prev is not None and list(prev["rdigests"][i]) == rdigest:
+                # clean chunk: reference the base version instead of re-writing
+                return None, {"ref": int(ctx.delta_base),
+                              "ulen": int(raw.size), "rdigest": rdigest}
+            if compress == "zstd" and _gate_allows_zstd(i, raw, ctx, dm):
+                stored = _compressor(ctx.zstd_level).compress(raw)
+                digest = _digest_chunk(stored)
+            elif compress == "zstd":
+                # gated raw chunk inside a zstd file: stored == raw bytes
+                stored = memoryview(raw)
+                return stored, {"clen": len(stored), "ulen": int(raw.size),
+                                "digest": rdigest, "rdigest": rdigest,
+                                "enc": "raw"}
+            else:
+                stored = memoryview(raw)
+                digest = rdigest          # stored bytes == raw bytes
             return stored, {"clen": len(stored), "ulen": int(raw.size),
-                            "digest": rdigest, "rdigest": rdigest,
-                            "enc": "raw"}
-        else:
-            stored = memoryview(raw)
-            digest = rdigest          # stored bytes == raw bytes
-        return stored, {"clen": len(stored), "ulen": int(raw.size),
-                        "digest": digest, "rdigest": rdigest}
+                            "digest": digest, "rdigest": rdigest}
 
-    encoded = run_jobs(
-        [lambda i=i, off=off: encode(i, off)
-         for i, off in enumerate(offsets)], ctx)
-    chunks_meta = [meta for _, meta in encoded]
-    header = json.dumps(
-        {
-            "fmt": CODEC_V2,
-            "dtype": _dtype_to_name(arr.dtype),
-            "shape": shape,
-            "compress": compress,
-            "checksum": "fletcher",   # v2 always digests (delta detector)
-            "chunk_bytes": chunk_bytes,
-            "nbytes": int(n),
-            "chunks": chunks_meta,
-        }
-    ).encode()
+        encoded = run_jobs(
+            [lambda i=i, off=off: encode(i, off)
+             for i, off in enumerate(offsets)], ctx)
+        chunks_meta = [meta for _, meta in encoded]
+        header = json.dumps(
+            {
+                "fmt": CODEC_V2,
+                "dtype": _dtype_to_name(arr.dtype),
+                "shape": shape,
+                "compress": compress,
+                "checksum": "fletcher",   # v2 always digests (delta detector)
+                "chunk_bytes": chunk_bytes,
+                "nbytes": int(n),
+                "chunks": chunks_meta,
+            }
+        ).encode()
     _atomic_write_file(
         path,
         [_MAGIC, len(header).to_bytes(8, "little"), header,
@@ -492,7 +498,8 @@ def read_array(path: Path, ctx: IOContext) -> np.ndarray:
                 f"{path}: format v{fmt} is newer than this reader understands"
             )
 
-    arr = _retrying(attempt, ctx)
+    with trace.TRACER.span("codec.read", file=path.name):
+        arr = _retrying(attempt, ctx)
     ctx.record_read(int(arr.nbytes))
     return arr
 
@@ -865,7 +872,11 @@ class ChunkRangeReader:
 
     def read(self, start: int, stop: int) -> memoryview:
         """Payload bytes [start, stop) — decoding only what the range needs."""
-        start, stop = int(start), int(stop)
+        with trace.TRACER.span("codec.read", file=self.path.name,
+                               nbytes=int(stop) - int(start)):
+            return self._read(int(start), int(stop))
+
+    def _read(self, start: int, stop: int) -> memoryview:
         if not 0 <= start <= stop <= self.nbytes:
             raise CheckpointError(
                 f"{self.path}: range [{start}, {stop}) outside payload of "

@@ -29,7 +29,7 @@ import shutil
 from pathlib import Path
 from typing import List, Optional, Set, Tuple
 
-from repro.core import metrics, trace
+from repro.core import metrics
 
 _TMP_PREFIX = ".tmp-"
 _VERSION_PREFIX = "v-"
@@ -306,8 +306,6 @@ class StorageTier(abc.ABC):
         """Feed one observed version-write duration into this tier's cost
         model (called by ``Checkpoint`` around every landed write; the
         scheduler consumes the estimate via :meth:`write_cost`)."""
-        trace.TRACER.emit("tier_cost", tier=self.label,
-                          seconds=seconds, nbytes=nbytes)
         # one choke point covers every tier's write latency/throughput
         metrics.inc("tier_writes", tier=self.label)
         metrics.inc("tier_write_bytes", nbytes, tier=self.label)
@@ -326,8 +324,6 @@ class StorageTier(abc.ABC):
         self._cost_ewma = seconds if prev is None else (
             (1.0 - self.COST_ALPHA) * prev + self.COST_ALPHA * seconds
         )
-        metrics.set_gauge("tier_cost_ewma_seconds", self._cost_ewma,
-                          tier=self.label)
 
     def write_cost(self):
         """Estimated seconds per version write: the EWMA of observed writes,

@@ -119,5 +119,7 @@ def snapshot(
         out_shape=jax.ShapeDtypeStruct(
             (n_chunks, _SUBLANES, _LANES), jnp.int32),
         interpret=interpret,
+        # its own line in a profile, apart from the staging program's ops
+        name="craft_snapshot",
     )(x3, prev_tiles)
     return jax.lax.bitcast_convert_type(out[:, 0, :width], jnp.uint32)

@@ -30,7 +30,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core import Box, Checkpoint
-from repro.core import metrics as craft_metrics
+from repro.core import trace as craft_trace
 from repro.core.aft import aft_zone
 from repro.data.pipeline import DataCursor, SyntheticTokens
 from repro.launch.compile_cache import setup_compile_cache
@@ -128,7 +128,9 @@ def run(tc: TrainConfig, comm=None, mesh=None,
     del shard, n_shards
 
     def body(comm_inner):
-        params, opt_state, _, _ = init_state(cfg, ocfg, mesh, rules, tc.seed)
+        with craft_trace.span("train.init_state"):
+            params, opt_state, _, _ = init_state(cfg, ocfg, mesh, rules,
+                                                 tc.seed)
         state_box = Box({"params": params, "opt": opt_state})
         # the box holds the only reference, so a restore that replaces the
         # state frees the initial one instead of keeping both on the device
@@ -136,11 +138,12 @@ def run(tc: TrainConfig, comm=None, mesh=None,
         step_box = Box(0)
         cursor = DataCursor(0)
 
-        cp = Checkpoint(tc.cp_name, comm_inner, env=env)
-        cp.add("state", state_box)
-        cp.add("step", step_box)
-        cp.add("cursor", FuncBox(cursor))
-        cp.commit()
+        with craft_trace.span("train.cp_setup"):
+            cp = Checkpoint(tc.cp_name, comm_inner, env=env)
+            cp.add("state", state_box)
+            cp.add("step", step_box)
+            cp.add("cursor", FuncBox(cursor))
+            cp.commit()
         cp.restart_if_needed()
         start_step = step_box.value
         if on_start is not None:
@@ -154,23 +157,28 @@ def run(tc: TrainConfig, comm=None, mesh=None,
         try:
             while step_box.value < tc.steps:
                 step_t0 = time.perf_counter()
-                batch_np = data.batch(cursor.step)
-                with jax.set_mesh(mesh):
-                    bspec = rules.spec(
-                        "batch", "seq", shape=batch_np["tokens"].shape)
-                    batch = {
-                        k: jax.device_put(
-                            v, jax.sharding.NamedSharding(mesh, bspec))
-                        for k, v in batch_np.items()
-                    }
-                    with use_rules(rules):
+                with craft_trace.span("train.step", step=step_box.value + 1):
+                    with craft_trace.span("train.batch"):
+                        batch_np = data.batch(cursor.step)
+                        with jax.set_mesh(mesh):
+                            bspec = rules.spec(
+                                "batch", "seq",
+                                shape=batch_np["tokens"].shape)
+                            batch = {
+                                k: jax.device_put(
+                                    v, jax.sharding.NamedSharding(mesh, bspec))
+                                for k, v in batch_np.items()
+                            }
+                    with craft_trace.span("train.dispatch"), \
+                            jax.set_mesh(mesh), use_rules(rules):
                         p, o, metrics = jit_step(
                             state_box.value["params"],
                             state_box.value["opt"], batch)
-                state_box.value = {"params": p, "opt": o}
-                cursor.step += 1
-                step_box.value += 1
-                loss = float(metrics["loss"])
+                    state_box.value = {"params": p, "opt": o}
+                    cursor.step += 1
+                    step_box.value += 1
+                    with craft_trace.span("train.sync"):
+                        loss = float(metrics["loss"])
                 losses.append(loss)
                 # compute-only step time (checkpoint writes excluded) feeds
                 # the scheduler's rework model and the result stats
@@ -178,11 +186,6 @@ def run(tc: TrainConfig, comm=None, mesh=None,
                 timer.observe(step_times[-1])
                 if cp.policy is not None and timer.last is not None:
                     cp.policy.observe_step_seconds(timer.last)
-                # live telemetry: step cadence + loss on the scoreboard
-                if craft_metrics.REGISTRY.enabled:
-                    craft_metrics.observe("train_step_seconds", timer.last)
-                    craft_metrics.set_gauge("train_loss", loss)
-                    craft_metrics.set_gauge("train_step", step_box.value)
                 if on_step is not None:
                     on_step(step_box.value, metrics)
                 if (tc.fail_at_step is not None

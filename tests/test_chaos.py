@@ -219,8 +219,10 @@ def test_readmission_rides_a_cheap_probe_not_the_version_write(tmp_path):
     from repro.core import storage
 
     arr = np.arange(256, dtype=np.float64)
+    # the cooldown must outlast the node write between the failed probe and
+    # the pfs turn, or the pfs breaker is half-open again by then
     cp = _mk(tmp_path, arr, CRAFT_CHAOS="on", CRAFT_IO_RETRIES=0,
-             CRAFT_BREAKER_THRESHOLD=1, CRAFT_BREAKER_COOLDOWN_S=0.05)
+             CRAFT_BREAKER_THRESHOLD=1, CRAFT_BREAKER_COOLDOWN_S=0.5)
     arr[...] = 1.0
     assert cp.update_and_write()
     cp.chaos.add("pfs:erofs:p=1")
@@ -228,7 +230,7 @@ def test_readmission_rides_a_cheap_probe_not_the_version_write(tmp_path):
     assert cp.update_and_write()             # trips
     assert cp.health["pfs"].state == "open"
 
-    _time.sleep(0.1)                         # cooldown elapses, fault persists
+    _time.sleep(0.6)                         # cooldown elapses, fault persists
     ops_before = cp.chaos.op_count("pfs", "write")
     arr[...] = 3.0
     assert cp.update_and_write()
@@ -238,7 +240,7 @@ def test_readmission_rides_a_cheap_probe_not_the_version_write(tmp_path):
     assert pfs.latest_version() == 1
 
     cp.chaos.clear("pfs")
-    _time.sleep(0.1)
+    _time.sleep(0.6)
     arr[...] = 4.0
     assert cp.update_and_write()             # probe re-closes, write lands
     assert cp.health["pfs"].state == "closed"

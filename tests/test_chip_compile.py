@@ -66,6 +66,8 @@ def test_snapshot_compiles(one_chip, with_hist):
                            use_pallas=True)
     hlo = _hlo(fn, _u32((16, 1 << 20), one_chip), _u32((16, 2), one_chip))
     assert "tpu_custom_call" in hlo
+    # the kernel's own name, so its device time has its own profile line
+    assert "%craft_snapshot" in hlo
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
