@@ -218,6 +218,43 @@ def _read_aux_array(path: Path, ctx: IOContext, root: Path) -> np.ndarray:
     return storage._restore_shape(payload, rdr.header, path)
 
 
+def _assemble_whole(ctx: IOContext, gshape: tuple, dtype: np.dtype, exts,
+                    where: str) -> np.ndarray:
+    """Read every shard file whole into one global host array.
+
+    A lone file spanning the whole leaf with the leaf's dtype is the leaf:
+    the writable array ``read_array`` decoded is returned as it is, with no
+    second buffer, coverage mask or copy (a read-only memory-tier view is
+    still copied).  Each ``restore.assemble`` span carries ``copied_bytes``,
+    0 on that path.
+    """
+    if len(exts) == 1 and exts[0][0] == tuple((0, s) for s in gshape):
+        arr = storage.read_array(exts[0][1], ctx)
+        if (arr.dtype == dtype and arr.shape == gshape
+                and arr.flags.writeable):
+            with trace.TRACER.span("restore.assemble", nbytes=arr.nbytes,
+                                   copied_bytes=0):
+                return arr
+        arrs = [arr]
+    else:
+        arrs = (storage.read_array(path, ctx) for _, path, _ in exts)
+    out = np.empty(gshape, dtype=dtype)
+    filled = np.zeros(gshape, dtype=bool) if out.size else None
+    for (ext, _path, _root), arr in zip(exts, arrs):
+        idx = tuple(slice(lo, hi) for lo, hi in ext)
+        with trace.TRACER.span("restore.assemble", nbytes=arr.nbytes,
+                               copied_bytes=arr.size * out.itemsize):
+            _assign_shard(out, idx, arr)
+            if filled is not None:
+                filled[idx] = True
+    if filled is not None and not filled.all():
+        raise CheckpointError(
+            f"incomplete shard coverage under {where} "
+            f"({int(filled.sum())}/{filled.size} elements)"
+        )
+    return out
+
+
 def _read_global_leaf(ctx: IOContext, gshape, dtype, sources, live,
                       where: str):
     """Assemble one global array from shard files written on any topology.
@@ -264,20 +301,7 @@ def _read_global_leaf(ctx: IOContext, gshape, dtype, sources, live,
         and any(e != full_ext for e in dst_exts)
     )
     if not use_range:
-        out = np.empty(gshape, dtype=dtype)
-        filled = np.zeros(gshape, dtype=bool) if out.size else None
-        for ext, path, _root in exts:
-            arr = storage.read_array(path, ctx)
-            idx = tuple(slice(lo, hi) for lo, hi in ext)
-            with trace.TRACER.span("restore.assemble", nbytes=arr.nbytes):
-                _assign_shard(out, idx, arr)
-                if filled is not None:
-                    filled[idx] = True
-        if filled is not None and not filled.all():
-            raise CheckpointError(
-                f"incomplete shard coverage under {where} "
-                f"({int(filled.sum())}/{filled.size} elements)"
-            )
+        out = _assemble_whole(ctx, gshape, dtype, exts, where)
         if live_is_jax:
             with trace.TRACER.span("restore.place", nbytes=out.nbytes):
                 return jax.device_put(out, live.sharding)

@@ -32,7 +32,9 @@ Every file starts ``CRFT`` + u64(header_len) + JSON header.  The header's
   jitted reference on CPU — instead of host zlib.  The header records per
   chunk ``{clen, ulen, digest}`` so a reader can verify integrity chunk by
   chunk and reject truncated files explicitly.  Chunk *encoding* fans out
-  across the IO worker pool via ``IOContext.fanout``.
+  across the IO worker pool via ``IOContext.fanout``; so does decoding, which
+  reads each chunk into its slice of the array's one buffer and checks the
+  same digest there on the host (the bytes are already in host memory).
 * **v2 (chunk-delta, fmt=2)** — the incremental codec (``CRAFT_DELTA``).
   Every chunk's *raw* bytes are digested first (``rdigest``); a chunk whose
   raw digest matches the previous version's manifest (threaded in via
@@ -467,7 +469,9 @@ def _write_array_v2(path: Path, arr: np.ndarray, ctx: IOContext) -> None:
 
 
 def read_array(path: Path, ctx: IOContext) -> np.ndarray:
-    """Read an array written by any codec version (v0 legacy or v1 chunked).
+    """Read an array written by any codec version (v0 legacy, v1 chunked or
+    v2 chunk-delta).  The result owns a writable buffer; chunked payloads
+    are decoded straight into it and their digests checked on the host.
 
     When ``ctx.array_cache`` holds a decoded array for ``path`` (memory-tier
     restore), it is returned directly as a read-only view — callers that need
@@ -548,7 +552,10 @@ def read_chunk_manifest(path: Path) -> Optional[dict]:
     }
 
 
-def _restore_shape(payload: bytes, header: dict, path: Path) -> np.ndarray:
+def _restore_shape(payload, header: dict, path: Path) -> np.ndarray:
+    """``payload`` as the header's dtype and shape.  A uint8 ndarray the
+    reader owns is viewed in place; bytes are copied once into a writable
+    buffer."""
     dtype = _dtype_from_name(header["dtype"])
     shape = header["shape"]
     expected = int(np.prod(shape, dtype=np.int64)) * dtype.itemsize
@@ -557,8 +564,9 @@ def _restore_shape(payload: bytes, header: dict, path: Path) -> np.ndarray:
             f"truncated payload in {path}: got {len(payload)} bytes, "
             f"expected {expected} for {header['dtype']}{tuple(shape)}"
         )
-    arr = np.frombuffer(bytearray(payload), dtype=dtype)
-    return arr.reshape(shape)
+    if not isinstance(payload, np.ndarray):
+        payload = np.frombuffer(bytearray(payload), dtype=np.uint8)
+    return payload.view(dtype).reshape(shape)
 
 
 def _read_payload_v0(fh, header: dict, path: Path, ctx: IOContext) -> np.ndarray:
@@ -579,56 +587,108 @@ def _read_payload_v0(fh, header: dict, path: Path, ctx: IOContext) -> np.ndarray
     return _restore_shape(payload, header, path)
 
 
-def _read_payload_v1(fh, header: dict, path: Path, ctx: IOContext) -> np.ndarray:
-    verify = ctx.checksum != "none" and header.get("checksum", "none") != "none"
-    # phase 1: sequential file IO — read every chunk's stored bytes
-    raw_chunks = []
-    for i, meta in enumerate(header["chunks"]):
-        stored = fh.read(meta["clen"])
-        if len(stored) != meta["clen"]:
+def _host_digest(data) -> List[int]:
+    """Read-side digest [s1, s2] of bytes already in host memory — the sums
+    every writer stored (:func:`_digest_chunk`, the batched pass, the device
+    snapshot), computed on the host with no device round trip."""
+    from repro.kernels.checksum import ops as checksum_ops
+
+    return checksum_ops.digest_host(data)
+
+
+def _pread_into(fd: int, buf, offset: int) -> int:
+    """Fill ``buf`` from ``fd`` at ``offset``; returns the bytes read (short
+    only at end of file).  Positional, so IO workers share one descriptor."""
+    view = memoryview(buf).cast("B")
+    got = 0
+    while got < len(view):
+        n = os.preadv(fd, [view[got:]], offset + got)
+        if n == 0:
+            break
+        got += n
+    return got
+
+
+def _read_chunks_into(fh, header: dict, path: Path, ctx: IOContext,
+                      verify: bool, resolve_ref=None) -> np.ndarray:
+    """Decode a chunked (v1/v2) payload into one owned, writable buffer.
+
+    Each chunk lands in its slice of the buffer and is verified there:
+    uncompressed chunks are read straight into place, compressed ones are
+    read, verified and inflated into it, v2 refs are resolved by
+    ``resolve_ref(i, meta)`` and copied in.  Truncation and trailing bytes
+    are found from the file size before any payload byte is read; chunk
+    jobs fan out through :func:`run_jobs`.
+    """
+    chunks = header["chunks"]
+    size = os.fstat(fh.fileno()).st_size
+    offs = []
+    off = fh.tell()
+    for i, meta in enumerate(chunks):
+        offs.append(off)
+        if "ref" in meta:
+            continue
+        clen = int(meta["clen"])
+        if size - off < clen:
             raise CheckpointError(
                 f"truncated payload in {path}: chunk {i} got "
-                f"{len(stored)}/{meta['clen']} bytes"
+                f"{max(0, size - off)}/{clen} bytes"
             )
-        raw_chunks.append(stored)
-    if fh.read(1):
+        off += clen
+    if size > off:
         raise CheckpointError(f"trailing bytes after last chunk in {path}")
 
-    # phase 2: digest verification + decompression fan out across the pool
-    def decode(i: int) -> bytes:
-        stored, meta = raw_chunks[i], header["chunks"][i]
-        if verify and _digest_chunk(stored) != list(meta["digest"]):
-            raise CheckpointError(f"checksum mismatch in {path} (chunk {i})")
-        if header["compress"] == "zstd" and meta.get("enc") != "raw":
-            if _zstd is None:  # pragma: no cover
-                raise CheckpointError(
-                    "file is zstd-compressed but zstandard missing")
-            try:
-                stored = _decompressor().decompress(stored)
-            except _zstd.ZstdError as exc:
-                raise CheckpointError(
-                    f"corrupt zstd chunk {i} in {path}: {exc}"
-                ) from exc
-        if len(stored) != meta["ulen"]:
+    starts = [0]
+    for meta in chunks:
+        starts.append(starts[-1] + int(meta["ulen"]))
+    flat = np.empty(starts[-1], dtype=np.uint8)
+    compress = header["compress"]
+    fd = fh.fileno()
+
+    def decode(i: int) -> None:
+        meta = chunks[i]
+        dst = flat[starts[i]: starts[i + 1]]
+        if "ref" in meta:
+            dst[:] = np.frombuffer(resolve_ref(i, meta), dtype=np.uint8)
+            return
+        clen = int(meta["clen"])
+        in_place = ((compress != "zstd" or meta.get("enc") == "raw")
+                    and clen == dst.size)
+        stored = dst if in_place else bytearray(clen)
+        got = _pread_into(fd, stored, offs[i])
+        if got != clen:
             raise CheckpointError(
-                f"corrupt chunk {i} in {path}: inflated to {len(stored)} "
+                f"truncated payload in {path}: chunk {i} got "
+                f"{got}/{clen} bytes"
+            )
+        if verify and _host_digest(stored) != list(meta["digest"]):
+            raise CheckpointError(f"checksum mismatch in {path} (chunk {i})")
+        if in_place:
+            return
+        out = _decompress_chunk(stored, compress, path, i, meta)
+        if len(out) != dst.size:
+            raise CheckpointError(
+                f"corrupt chunk {i} in {path}: inflated to {len(out)} "
                 f"bytes, expected {meta['ulen']}"
             )
-        return stored
+        dst[:] = np.frombuffer(out, dtype=np.uint8)
 
-    parts = run_jobs(
-        [lambda i=i: decode(i) for i in range(len(raw_chunks))], ctx)
-    out = b"".join(parts)
-    if len(out) != header["nbytes"]:
+    run_jobs([lambda i=i: decode(i) for i in range(len(chunks))], ctx)
+    if flat.size != header["nbytes"]:
         raise CheckpointError(
-            f"truncated payload in {path}: got {len(out)} bytes, "
+            f"truncated payload in {path}: got {flat.size} bytes, "
             f"expected {header['nbytes']}"
         )
-    return _restore_shape(out, header, path)
+    return _restore_shape(flat, header, path)
 
 
-def _decompress_chunk(stored: bytes, compress: str, path: Path, i: int,
-                      meta: Optional[dict] = None) -> bytes:
+def _read_payload_v1(fh, header: dict, path: Path, ctx: IOContext) -> np.ndarray:
+    verify = ctx.checksum != "none" and header.get("checksum", "none") != "none"
+    return _read_chunks_into(fh, header, path, ctx, verify)
+
+
+def _decompress_chunk(stored, compress: str, path: Path, i: int,
+                      meta: Optional[dict] = None):
     if compress != "zstd" or (meta is not None and meta.get("enc") == "raw"):
         return stored
     if _zstd is None:  # pragma: no cover
@@ -643,24 +703,6 @@ def _read_payload_v2(fh, header: dict, path: Path, ctx: IOContext) -> np.ndarray
     """Delta-aware reader: literal chunks come from this file, ref chunks are
     resolved from the base versions' copies of the same relative path."""
     verify = ctx.checksum != "none"
-    chunks = header["chunks"]
-    # phase 1: sequential file IO — slurp every *literal* chunk's bytes
-    raw_chunks: List[Optional[bytes]] = []
-    for i, meta in enumerate(chunks):
-        if "ref" in meta:
-            raw_chunks.append(None)
-            continue
-        stored = fh.read(meta["clen"])
-        if len(stored) != meta["clen"]:
-            raise CheckpointError(
-                f"truncated payload in {path}: chunk {i} got "
-                f"{len(stored)}/{meta['clen']} bytes"
-            )
-        raw_chunks.append(stored)
-    if fh.read(1):
-        raise CheckpointError(f"trailing bytes after last chunk in {path}")
-
-    # phase 2: verify/decompress literals and resolve refs across the pool
     hcache: dict = {}   # str(base file) -> (header, per-chunk payload offsets)
     rel = None
     if ctx.rel_root is not None:
@@ -669,31 +711,12 @@ def _read_payload_v2(fh, header: dict, path: Path, ctx: IOContext) -> np.ndarray
         except ValueError:
             rel = None
 
-    def decode(i: int) -> bytes:
-        meta = chunks[i]
-        if "ref" in meta:
-            return _resolve_ref_chunk(
-                rel, path, ctx, int(meta["ref"]), i, int(meta["ulen"]),
-                list(meta["rdigest"]), verify, hcache)
-        stored = raw_chunks[i]
-        if verify and _digest_chunk(stored) != list(meta["digest"]):
-            raise CheckpointError(f"checksum mismatch in {path} (chunk {i})")
-        out = _decompress_chunk(stored, header["compress"], path, i, meta)
-        if len(out) != meta["ulen"]:
-            raise CheckpointError(
-                f"corrupt chunk {i} in {path}: inflated to {len(out)} "
-                f"bytes, expected {meta['ulen']}"
-            )
-        return out
+    def resolve_ref(i: int, meta: dict) -> bytes:
+        return _resolve_ref_chunk(
+            rel, path, ctx, int(meta["ref"]), i, int(meta["ulen"]),
+            list(meta["rdigest"]), verify, hcache)
 
-    parts = run_jobs([lambda i=i: decode(i) for i in range(len(chunks))], ctx)
-    out = b"".join(parts)
-    if len(out) != header["nbytes"]:
-        raise CheckpointError(
-            f"truncated payload in {path}: got {len(out)} bytes, "
-            f"expected {header['nbytes']}"
-        )
-    return _restore_shape(out, header, path)
+    return _read_chunks_into(fh, header, path, ctx, verify, resolve_ref)
 
 
 def _resolve_ref_chunk(
@@ -760,7 +783,7 @@ def _resolve_ref_chunk(
     if len(stored) != int(bmeta["clen"]):
         raise CheckpointError(
             f"truncated delta base chunk {idx} in {bpath}")
-    if verify and _digest_chunk(stored) != list(bmeta["digest"]):
+    if verify and _host_digest(stored) != list(bmeta["digest"]):
         raise CheckpointError(
             f"checksum mismatch in delta base {bpath} (chunk {idx})")
     out = _decompress_chunk(stored, bheader.get("compress", "none"),
@@ -778,7 +801,7 @@ def _resolve_ref_chunk(
         raw_dig = (list(bmeta["digest"])
                    if bheader.get("compress", "none") != "zstd"
                    or bmeta.get("enc") == "raw"
-                   else _digest_chunk(out))
+                   else _host_digest(out))
         if raw_dig != list(rdigest):
             raise CheckpointError(
                 f"delta ref mismatch: base {bpath} chunk {idx} content "
@@ -939,7 +962,7 @@ class ChunkRangeReader:
                     f"truncated payload in {self.path}: chunk {i} got "
                     f"{len(stored)}/{meta['clen']} bytes"
                 )
-            if verify and _digest_chunk(stored) != list(meta["digest"]):
+            if verify and _host_digest(stored) != list(meta["digest"]):
                 raise CheckpointError(
                     f"checksum mismatch in {self.path} (chunk {i})")
             data = _decompress_chunk(

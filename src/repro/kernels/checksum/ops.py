@@ -69,6 +69,34 @@ def _rows_checksum_np(body: np.ndarray) -> list:
     return [[int(a), int(b)] for a, b in zip(s1, s2)]
 
 
+_HOST_BLOCK_WORDS = 1 << 18                     # 1 MiB of payload a block
+_HOST_WEIGHTS = np.arange(1, _HOST_BLOCK_WORDS + 1, dtype=np.uint32)
+
+
+def digest_host(buf: Union[bytes, bytearray, np.ndarray]) -> list:
+    """[s1, s2] of a byte buffer computed on the host — bit-identical to
+    :func:`digest_bytes` (ragged tail zero-padded to a word), with no device
+    transfer and no dispatch: the read path verifies bytes already in host
+    memory.  Blocks of words are combined by associativity
+    (``s2 += offset * s1``); uint32 ``np.dot`` wraps mod 2^32 and releases
+    the GIL, so IO workers digest in parallel."""
+    arr = _as_u8(buf)
+    n_words, rem = divmod(arr.size, 4)
+    words = arr[: n_words * 4].view(np.uint32)
+    s1 = s2 = 0
+    for off in range(0, n_words, _HOST_BLOCK_WORDS):
+        block = words[off: off + _HOST_BLOCK_WORDS]
+        b1 = int(np.sum(block, dtype=np.uint32))
+        b2 = int(np.dot(block, _HOST_WEIGHTS[: block.size]))
+        s1 += b1
+        s2 += b2 + off * b1
+    if rem:
+        tail = int.from_bytes(arr[n_words * 4:].tobytes(), "little")
+        s1 += tail
+        s2 += (n_words + 1) * tail
+    return [s1 & 0xFFFFFFFF, s2 & 0xFFFFFFFF]
+
+
 def digest_chunks(buf: Union[bytes, bytearray, np.ndarray],
                   chunk_bytes: int, *, use_pallas: bool = None) -> list:
     """Per-chunk (s1, s2) digests of ``buf`` split every ``chunk_bytes``.

@@ -151,6 +151,16 @@ class TestChecksum:
         corrupted[1234] ^= 0x40
         assert ck_ops.digest_bytes(bytes(corrupted)) != d1
 
+    @pytest.mark.parametrize("n", [0, 1, 3, 4, 4099, (1 << 20) + 7,
+                                   (3 << 20) + 12])
+    def test_host_digest_matches_device_digest(self, n):
+        """digest_host (the read path's check) against digest_bytes, across
+        word tails and the host's 1 MiB block seams, on a misaligned view."""
+        rng = np.random.default_rng(n)
+        buf = rng.integers(0, 256, n + 1, dtype=np.uint8)[1:]
+        assert ck_ops.digest_host(buf) == list(ck_ops.digest_bytes(buf))
+        assert ck_ops.digest_host(buf.tobytes()) == ck_ops.digest_host(buf)
+
     def test_pallas_interpret_matches_ref(self):
         from repro.kernels.checksum.kernel import checksum as ck
         rng = np.random.default_rng(4)

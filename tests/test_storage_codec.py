@@ -141,6 +141,156 @@ class TestTruncationAndCorruption:
         with pytest.raises(CheckpointError, match="missing"):
             storage.read_array(tmp_path / "nope.bin", ctx_v1())
 
+    @pytest.mark.parametrize("codec", [1, 2])
+    def test_trailing_bytes_rejected(self, tmp_path, rng, codec):
+        arr = rng.integers(0, 255, 300, dtype=np.uint8)
+        p = tmp_path / "a.bin"
+        storage.write_array(p, arr, IOContext(codec_version=codec,
+                                              chunk_bytes=64))
+        p.write_bytes(p.read_bytes() + b"x")
+        with pytest.raises(CheckpointError, match="trailing bytes"):
+            storage.read_array(p, IOContext())
+
+
+# ------------------------------------------------------------ one-buffer read
+CHUNK = 64
+SIZES = {"empty": 0, "sub_chunk": 50, "exact": 4 * CHUNK,
+         "ragged": 4 * CHUNK + 44, "ragged_odd": 3 * CHUNK + 11}
+
+
+def _payload_offset(path):
+    raw = path.read_bytes()
+    return 12 + int.from_bytes(raw[4:12], "little")
+
+
+def _owns_buffer(arr):
+    """True when ``arr`` (or the array it views) owns its memory."""
+    while isinstance(arr.base, np.ndarray):
+        arr = arr.base
+    return arr.base is None and arr.flags.owndata
+
+
+def _v2_chain(tmp_path, base, head, **kw):
+    """``base`` as v-1 and ``head`` as v-2 of a delta chain: head's chunks
+    equal to base's are refs.  Returns head's file and its read context."""
+    v1, v2 = tmp_path / "v-1", tmp_path / "v-2"
+    v1.mkdir()
+    v2.mkdir()
+    db = {}
+    storage.write_array(v1 / "a.bin", base, IOContext(
+        codec_version=2, chunk_bytes=CHUNK, rel_root=v1, chunks_db=db, **kw))
+    storage.write_array(v2 / "a.bin", head, IOContext(
+        codec_version=2, chunk_bytes=CHUNK, rel_root=v2, delta_prev=db,
+        delta_base=1, **kw))
+    return v2 / "a.bin", IOContext(rel_root=v2, base_dirs={1: v1})
+
+
+@pytest.fixture()
+def pool():
+    p = AsyncWriter(workers=3)
+    yield p
+    p.close()
+
+
+class TestOneBufferDecode:
+    @pytest.mark.parametrize("fanout", [False, True])
+    @pytest.mark.parametrize("size", list(SIZES))
+    @pytest.mark.parametrize("checksum", ["fletcher", "none"])
+    @pytest.mark.parametrize("compress", ["none", "zstd"])
+    @pytest.mark.parametrize("codec", [1, 2])
+    def test_roundtrip_writable_and_owned(self, tmp_path, rng, pool, codec,
+                                          compress, checksum, size, fanout):
+        if compress == "zstd":
+            pytest.importorskip("zstandard")
+        arr = rng.integers(0, 4, SIZES[size], dtype=np.uint8)
+        p = tmp_path / "a.bin"
+        storage.write_array(p, arr, IOContext(
+            codec_version=codec, chunk_bytes=CHUNK, compress=compress,
+            checksum=checksum))
+        out = storage.read_array(p, IOContext(
+            checksum=checksum, fanout=pool.run_parallel if fanout else None))
+        assert out.dtype == arr.dtype and out.shape == arr.shape
+        assert out.tobytes() == arr.tobytes()
+        assert out.flags.writeable and _owns_buffer(out)
+
+    @pytest.mark.parametrize("chunk", [0, 2, 4], ids=["first", "middle",
+                                                       "ragged_tail"])
+    @pytest.mark.parametrize("codec", [1, 2])
+    def test_corrupt_chunk_is_named(self, tmp_path, rng, pool, codec, chunk):
+        arr = rng.integers(0, 255, SIZES["ragged_odd"] + CHUNK,
+                           dtype=np.uint8)
+        p = tmp_path / "a.bin"
+        storage.write_array(p, arr, IOContext(codec_version=codec,
+                                              chunk_bytes=CHUNK))
+        raw = bytearray(p.read_bytes())
+        raw[_payload_offset(p) + chunk * CHUNK + 5] ^= 0x10
+        p.write_bytes(bytes(raw))
+        with pytest.raises(CheckpointError,
+                           match=rf"checksum mismatch in .*\(chunk {chunk}\)"):
+            storage.read_array(p, IOContext(fanout=pool.run_parallel))
+
+    def test_v2_refs_resolve_into_place(self, tmp_path, rng, pool):
+        base = rng.integers(0, 255, SIZES["ragged_odd"], dtype=np.uint8)
+        head = base.copy()
+        head[CHUNK + 3] ^= 0xFF                 # only chunk 1 is a literal
+        p, ctx = _v2_chain(tmp_path, base, head)
+        assert sum("ref" in c for c in storage.read_chunk_manifest(p)[
+            "chunks"]) == 3
+        out = storage.read_array(p, IOContext(
+            rel_root=ctx.rel_root, base_dirs=ctx.base_dirs,
+            fanout=pool.run_parallel))
+        assert out.tobytes() == head.tobytes()
+        assert out.flags.writeable and _owns_buffer(out)
+
+
+class TestReadPathStaysOnHost:
+    """Read-side digests are computed on the host: with every device digest
+    entry point made to raise, reads still verify and still reject rot."""
+
+    @pytest.fixture()
+    def no_device(self, monkeypatch):
+        from repro.kernels.checksum import ops as checksum_ops
+
+        def boom(*a, **k):
+            raise AssertionError("device digest on the read path")
+
+        def arm():
+            monkeypatch.setattr(checksum_ops, "digest_array", boom)
+            monkeypatch.setattr(checksum_ops, "_rows_checksum", boom)
+        return arm
+
+    @pytest.mark.parametrize("kind", ["v1", "v1_zstd", "v2_refs"])
+    def test_verified_read_needs_no_device(self, tmp_path, rng, no_device,
+                                           kind):
+        if kind == "v1_zstd":
+            pytest.importorskip("zstandard")
+        arr = rng.integers(0, 4, SIZES["ragged_odd"], dtype=np.uint8)
+        if kind == "v2_refs":
+            head = arr.copy()
+            head[0] ^= 0xFF
+            p, ctx = _v2_chain(tmp_path, arr, head)
+            arr, rot = head, ctx.base_dirs[1] / "a.bin"   # a referenced base
+            rot_chunk = 2
+        else:
+            p = tmp_path / "a.bin"
+            storage.write_array(p, arr, ctx_v1(
+                chunk_bytes=CHUNK,
+                compress="zstd" if kind == "v1_zstd" else "none"))
+            ctx, rot, rot_chunk = ctx_v1(), p, 1
+        no_device()
+        assert storage.read_array(p, ctx).tobytes() == arr.tobytes()
+        rdr = storage.ChunkRangeReader(p, ctx)
+        assert bytes(rdr.read(5, 150)) == arr[5:150].tobytes()
+
+        raw = bytearray(rot.read_bytes())
+        hdr = storage.read_chunk_manifest(rot)["chunks"]
+        off = _payload_offset(rot) + sum(c["clen"] for c in hdr[:rot_chunk])
+        raw[off + 1] ^= 0x01
+        rot.write_bytes(bytes(raw))
+        with pytest.raises(CheckpointError,
+                           match=rf"checksum mismatch .*chunk {rot_chunk}\)"):
+            storage.read_array(p, ctx)
+
 
 # ------------------------------------------------------------------ fanout
 class TestFanoutPool:

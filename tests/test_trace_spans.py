@@ -281,6 +281,58 @@ def test_staged_snapshot_spans(tmp_path):
     assert _by_name(got, "snapshot.d2h")[0]["nbytes"] == 3 * 4096
 
 
+def test_single_file_leaf_restores_without_a_copy(tmp_path):
+    trace.install(str(tmp_path / "t.jsonl"))
+    env = _env(tmp_path, CRAFT_CHUNK_BYTES=4096, CRAFT_IO_WORKERS=3)
+    rng = np.random.default_rng(1)
+    state = {"w": jnp.asarray(rng.standard_normal((33, 70)), jnp.bfloat16),
+             "m": jnp.asarray(rng.standard_normal(5001), jnp.float32)}
+    cp = Checkpoint("p", env=env)
+    cp.add("state", Box(state))
+    cp.commit()
+    assert cp.update_and_write(1)
+    cp.wait()
+    cp.close()
+    trace.flush()
+    live = Box(jax.tree_util.tree_map(jnp.zeros_like, state))
+    cp = Checkpoint("p", env=env)
+    cp.add("state", live)
+    cp.commit()
+    assert cp.restart_if_needed()
+    cp.close()
+    for k in state:
+        assert np.asarray(live.value[k]).tobytes() == \
+            np.asarray(state[k]).tobytes()
+    got = _by_name(trace.spans(), "restore.assemble")
+    assert sorted(s["nbytes"] for s in got) == [33 * 70 * 2, 5001 * 4]
+    assert [s["copied_bytes"] for s in got] == [0, 0]
+
+
+def test_two_shard_leaf_is_copied_and_checked_for_coverage(tmp_path):
+    from repro.core import checkpointables, storage
+    from repro.core.cpbase import CheckpointError, IOContext
+
+    trace.install(str(tmp_path / "t.jsonl"))
+    full = np.arange(48, dtype=np.float32).reshape(8, 6)
+    ctx = IOContext(chunk_bytes=64)
+    sources = []
+    for k, (lo, hi) in enumerate([(0, 4), (4, 8)]):
+        p = tmp_path / f"shard-{k}.bin"
+        storage.write_array(p, full[lo:hi], ctx)
+        sources.append(([[lo, hi], [0, 6]], p, None))
+    live = jnp.zeros((8, 6), jnp.float32)
+    out = checkpointables._read_global_leaf(
+        ctx, full.shape, full.dtype, sources, live, "leaf 0")
+    np.testing.assert_array_equal(np.asarray(out), full)
+    got = _by_name(trace.spans(), "restore.assemble")
+    assert [s["copied_bytes"] for s in got] == [96, 96]
+    with pytest.raises(CheckpointError,
+                       match=r"incomplete shard coverage under leaf 0 "
+                             r"\(24/48 elements\)"):
+        checkpointables._read_global_leaf(
+            ctx, full.shape, full.dtype, sources[1:], live, "leaf 0")
+
+
 # ------------------------------------------------------- readers of a file
 def test_replay_and_top_read_a_file_with_spans(tmp_path):
     from repro import top
