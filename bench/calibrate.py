@@ -9,6 +9,7 @@ In one process, for each seed: the program's first steps through
 harness compares them (the lower reading); for each control seed: the
 reference computed in float8 put in the program's place (the control), and
 the reference with half of every batch left out (the half-batch fault).
+The reference is the configuration's own (its ``"reference"`` file).
 A state left unchanged reads 1 on ``change_gap`` and needs no run.  The
 benchmark's own runs never run this.
 """
@@ -27,7 +28,7 @@ ROOT = Path(__file__).resolve().parents[1]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 from bench import harness  # noqa: E402
-from bench import reference as ref  # noqa: E402
+from bench import ref_training as rt  # noqa: E402
 
 STEPS = 3   # the harness compares the first three steps
 
@@ -51,7 +52,7 @@ def program_readings(run: harness.Run, arch: str, steps: int) -> dict:
             harness.first_gradient(run, st, rec)
         if step == steps:
             box["change"] = np.asarray(
-                ref.diff_norms(st["params"], rec.pop("init")), np.float64)
+                rt.diff_norms(st["params"], rec.pop("init")), np.float64)
 
     tc = harness.train_config(run, arch, steps, 10 ** 6)
     out = train.run(tc, env=harness.craft_env(run.cell, run.workdir),
@@ -59,6 +60,18 @@ def program_readings(run: harness.Run, arch: str, steps: int) -> dict:
     del out
     rec["change_norms"] = box["change"]
     return rec
+
+
+def reference_readings(run: harness.Run, **kw) -> dict:
+    """The configuration's reference over the first ``STEPS`` steps of the
+    run's seed, at the cell's sizes; ``kw`` puts the control or a fault in
+    (``quant``, ``rows``)."""
+    data = {"seq_len": run.seq_len, "global_batch": run.batch,
+            "zipf_a": run.cell.traffic["zipf_a"]}
+    opt = dict(run.opt, total_steps=max(STEPS, 10))
+    return rt.train_readings(
+        run.reference, run.prog_seed, run.model, opt, data, STEPS,
+        block_rows=int(run.cell.params.get("reference_block_rows", 1)), **kw)
 
 
 def main(argv=None) -> int:
@@ -79,10 +92,6 @@ def main(argv=None) -> int:
     harness.device_info(cell.chips, require_tpu=True)
     arch = harness.register(cell)
     out = open(args.out, "a") if args.out else None
-    data = {"seq_len": int(cell.traffic["seq_len"]),
-            "global_batch": int(cell.params["global_batch"]),
-            "zipf_a": cell.traffic["zipf_a"]}
-    block = int(cell.params.get("reference_block_rows", 1))
 
     def emit(rec):
         line = json.dumps(rec)
@@ -95,10 +104,8 @@ def main(argv=None) -> int:
     controls = [int(s) for s in args.control_seeds.split(",") if s]
     for seed in sorted(set(seeds) | set(controls)):
         run = harness.Run(cell, seed, 0.0, False, harness.WORKDIR, 0.0)
-        opt = dict(run.opt, total_steps=max(STEPS, 10))
         t0 = time.perf_counter()
-        full = ref.train_readings(run.prog_seed, run.model, opt, data,
-                                  STEPS, block_rows=block)
+        full = reference_readings(run)
         t_ref = time.perf_counter() - t0
         if seed in seeds:
             run.probes = Probes().install()
@@ -110,14 +117,10 @@ def main(argv=None) -> int:
                   **harness.training_numbers(got, full),
                   "losses": got["losses"], "ref_losses": full["losses"]})
         if seed in controls:
-            low = ref.train_readings(run.prog_seed, run.model, opt, data,
-                                     STEPS, quant="fp8",
-                                     block_rows=block)
+            low = reference_readings(run, quant="fp8")
             emit({"kind": "control_fp8", "seed": seed,
                   **harness.training_numbers(low, full)})
-            half = ref.train_readings(
-                run.prog_seed, run.model, opt, data, STEPS,
-                rows=slice(0, data["global_batch"] // 2), block_rows=block)
+            half = reference_readings(run, rows=slice(0, run.batch // 2))
             emit({"kind": "fault_half_batch", "seed": seed,
                   **harness.training_numbers(half, full)})
     if out is not None:

@@ -20,9 +20,10 @@ makes them.  Two traffic modes exist:
   passed; the one under way then is completed and counted.
 
 After the window, and after the device peak has been read and the
-program's state freed, the reference (``bench/reference.py``) recomputes
-the first steps from the seed, and every compared number is printed with
-its limit.
+program's state freed, the reference recomputes the first steps from the
+seed (``bench/ref_training.py`` with the architecture module that the
+configuration's ``"reference"`` key names), and every compared number is
+printed with its limit.
 """
 from __future__ import annotations
 
@@ -31,6 +32,7 @@ import gc
 import importlib.util
 import json
 import os
+import re
 import shutil
 import sys
 import time
@@ -59,6 +61,7 @@ class Cell:
     params: dict
     end_to_end: list
     per_layer: list
+    reference: Path             # the configuration's architecture module
 
 
 def _load_json(path: Path) -> dict:
@@ -81,16 +84,38 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
                        f"{', '.join(sorted(cells))}")
     w = cells[name]
     bench = root / "bench"
+    config = _load_json(bench / "configs" / f"{w['config']}.json")
+    if "reference" not in config:
+        raise ValueError(f"configuration {w['config']!r} names no "
+                         f"\"reference\" file for its architecture")
+    ref_path = root / config["reference"]
+    if not ref_path.is_file():
+        raise ValueError(f"configuration {w['config']!r}: no reference "
+                         f"file {config['reference']!r}")
     e2e = [m for m in spec["end_to_end"] if _applies(m, name, ())]
     names = {m["name"] for m in e2e}
     return Cell(
-        name=name, chips=int(w["chips"]),
-        config=_load_json(bench / "configs" / f"{w['config']}.json"),
+        name=name, chips=int(w["chips"]), config=config,
         traffic=_load_json(bench / "traffic" / f"{w['traffic']}.json"),
         params=_load_json(bench / "workloads" / f"{name}.json"),
         end_to_end=e2e,
         per_layer=[m for m in spec["per_layer"]
-                   if _applies(m, name, names)])
+                   if _applies(m, name, names)],
+        reference=ref_path)
+
+
+def load_reference(path: Path):
+    """The architecture module in ``path`` (a configuration's
+    ``"reference"``, keeping the contract of ``bench/ref_training.py``),
+    loaded from its file once per process."""
+    path = Path(path).resolve()
+    name = "bench_reference_" + re.sub(r"\W", "_", str(path))
+    if name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        sys.modules[name] = mod
+    return sys.modules[name]
 
 
 def read_metric(name: str, run) -> Optional[float]:
@@ -148,6 +173,7 @@ class Run:
     def __init__(self, cell: Cell, seed: int, seconds: float, trace: bool,
                  workdir: Path, t_start: float):
         self.cell = cell
+        self.reference = load_reference(cell.reference)
         self.model = cell.config["model"]
         self.opt = cell.config["optimizer"]
         self.seed = seed
@@ -259,12 +285,12 @@ def first_gradient(run: Run, state, rec: dict) -> None:
     """The first step's clipped gradient as the optimizer got it, read back
     from Adam's first moment after one step (m = (1 - beta1) g): its
     per-leaf norms and which rows of the input embedding it left zero."""
-    from bench import reference as ref
+    from bench import ref_training as rt
 
     m = state["opt"]["m"]
-    rec["grad_norms"] = np.asarray(ref.leaf_norms(m), np.float64) / (
+    rec["grad_norms"] = np.asarray(rt.leaf_norms(m), np.float64) / (
         1.0 - run.opt["beta1"])
-    rec["embed_zero_rows"] = np.asarray(ref.zero_embed_rows(m))
+    rec["embed_zero_rows"] = np.asarray(rt.zero_embed_rows(m))
 
 
 # --------------------------------------------------------- train and save
@@ -273,7 +299,7 @@ def run_train_save(run: Run, arch: str) -> dict:
     import jax.numpy as jnp
 
     from bench import readback
-    from bench import reference as ref
+    from bench import ref_training as rt
     from repro.launch import train
 
     K = int(run.cell.params["save_every"])
@@ -297,7 +323,7 @@ def run_train_save(run: Run, arch: str) -> dict:
             first_gradient(run, st, rec)
             readback.fingerprint(st).block_until_ready()
         if step == 3:
-            rec["change_norms"] = np.asarray(ref.diff_norms(
+            rec["change_norms"] = np.asarray(rt.diff_norms(
                 st["params"], rec.pop("init_params")), np.float64)
         if step == s0:
             # warm-up save, made durable: loads the snapshot programs that
@@ -408,14 +434,14 @@ def _check_training(run: Run, losses, rec: dict) -> None:
     """Compare the first steps with the reference: ``losses[i]`` holds the
     program's readings of step i + 1 (several where resumes repeat it);
     ``rec`` holds the first gradient and the change after the last step."""
-    from bench import reference as ref
+    from bench import ref_training as rt
 
     data = {"seq_len": run.seq_len, "global_batch": run.batch,
             "zipf_a": run.cell.traffic["zipf_a"]}
     opt = dict(run.opt, total_steps=run.opt_total_steps)
-    r = ref.train_readings(run.prog_seed, run.model, opt, data, len(losses),
-                           block_rows=int(run.cell.params.get(
-                               "reference_block_rows", 1)))
+    r = rt.train_readings(run.reference, run.prog_seed, run.model, opt, data,
+                          len(losses), block_rows=int(run.cell.params.get(
+                              "reference_block_rows", 1)))
     limits = run.cell.params["limits"]
     for name, value in training_numbers(
             {"losses": losses, **rec}, r).items():
@@ -425,19 +451,19 @@ def _check_training(run: Run, losses, rec: dict) -> None:
 
 def training_numbers(got: dict, want: dict) -> dict:
     """The numbers ``correct`` compares for the first training steps."""
-    from bench import reference as ref
+    from bench import ref_training as rt
 
     losses = [x if isinstance(x, list) else [x] for x in got["losses"]]
     return {
-        "loss_gap": max(ref.loss_gap(g, [w] * len(g))
+        "loss_gap": max(rt.loss_gap(g, [w] * len(g))
                         for g, w in zip(losses, want["losses"])),
-        "grad_gap": ref.worst_leaf_gap(got["grad_norms"],
-                                       want["grad_norms"]),
+        "grad_gap": rt.worst_leaf_gap(got["grad_norms"],
+                                      want["grad_norms"]),
         "embed_rows_differing": int(np.sum(
             got["embed_zero_rows"] != want["embed_zero_rows"])),
-        "change_gap": ref.worst_leaf_gap(
+        "change_gap": rt.worst_leaf_gap(
             got["change_norms"], want["change_norms"],
-            ref.moving_leaves(want["grad_norms"])),
+            rt.moving_leaves(want["grad_norms"])),
     }
 
 
@@ -447,7 +473,7 @@ def run_resume(run: Run, arch: str) -> dict:
     import jax.numpy as jnp
 
     from bench import readback
-    from bench import reference as ref
+    from bench import ref_training as rt
     from repro.launch import train
 
     first = int(run.cell.traffic["setup_steps"])
@@ -500,7 +526,7 @@ def run_resume(run: Run, arch: str) -> dict:
         return got
 
     warm = resume_once()
-    rec["change_norms"] = np.asarray(ref.diff_norms(
+    rec["change_norms"] = np.asarray(rt.diff_norms(
         warm.pop("state")["params"], rec.pop("init_params")), np.float64)
     rec["resumes"] = [warm]
     rec["counters0"] = _counters(run)

@@ -1,32 +1,31 @@
-"""Plain reference of what the timed path computes: a dense GQA decoder
-(RMSNorm, RoPE, causal attention, SwiGLU), its next-token loss, its
-gradient and the AdamW step that the configuration states.
+"""Plain reference of a dense GQA decoder (RMSNorm, RoPE, causal attention
+with an optional sliding window, SwiGLU), the architecture that the
+configurations naming ``"reference": "bench/reference.py"`` state.  It
+keeps the contract of ``bench/ref_training.py``: ``init_params``,
+``nll_sum``, ``model_flops_per_token`` and ``TINY``; the training step,
+the batches and the comparison live there.
 
-It imports nothing of the program.  It builds its own weights and token
-batches from the seed with the same random streams the configuration
-names (``jax.random`` truncated normals scaled by 1/sqrt(fan-in), cast to
-bfloat16; Philox zipf tokens), and computes in float32 at the highest
-matmul precision.  Parameters are stored in bfloat16 between steps, as the
-configuration states (bf16 parameters, fp32 moments, no master copy).
-
-``quant="fp8"`` computes every matrix product on operands rounded to
-float8 e4m3 with a per-tensor scale: the control that has to come out as
-not correct.  ``rows`` restricts the loss to a subset of each batch's rows
-(the half-batch fault).
+It imports nothing of the program.  It builds its own weights from the
+seed with the same random streams the configuration names
+(``jax.random`` truncated normals scaled by 1/sqrt(fan-in), cast to
+bfloat16).  The counts below are of the same model: model FLOPs count the
+matrix multiplications of the forward and backward passes (3x the
+forward), with causal attention counted over the keys each query attends
+to; recomputation under remat does not count.
 """
 from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
-
-import numpy as np
 
 import jax
 import jax.numpy as jnp
 
-HIGHEST = jax.lax.Precision.HIGHEST
-F8_MAX = 448.0
+from bench.ref_training import mm, rms, rope
+
+# the model keys a CPU test shrinks; a null window stays null
+TINY = dict(n_layers=2, d_model=64, vocab=512, n_heads=4, n_kv_heads=2,
+            head_dim=16, d_ff=128, window=16)
 
 
 # ----------------------------------------------------------------- inputs
@@ -68,66 +67,29 @@ def init_params(seed: int, m: dict):
     return params
 
 
-def token_batch(seed: int, step: int, vocab: int, seq_len: int, rows: int,
-                zipf_a: float):
-    """The batch of step ``step`` (0-based): zipf ids folded into the
-    vocabulary, labels the tokens shifted left."""
-    rng = np.random.Generator(np.random.Philox(
-        key=[(seed << 32) | (step & 0xFFFFFFFF), 0xC0FFEE]))
-    raw = rng.zipf(zipf_a, size=(rows, seq_len + 1))
-    ids = (raw - 1) % vocab
-    return ids[:, :-1].astype(np.int32), ids[:, 1:].astype(np.int32)
-
-
 # ---------------------------------------------------------------- forward
-def _q8(x):
-    s = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / F8_MAX
-    return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
-
-
-def _mm(eq, a, b, quant):
-    if quant == "fp8":
-        a, b = _q8(a), _q8(b)
-    return jnp.einsum(eq, a, b, precision=HIGHEST)
-
-
-def _rms(x, w, eps):
-    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + eps) * w
-
-
-def _rope(x, theta):
-    d, n = x.shape[-1], x.shape[-2]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = jnp.arange(n, dtype=jnp.float32)[:, None] * inv
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-    return out.reshape(x.shape)
-
-
 def _layer(p, x, m, quant):
     eps, group = m["norm_eps"], m["n_heads"] // m["n_kv_heads"]
-    h = _rms(x, p["ln1"], eps)
-    q = _rope(_mm("bld,dhk->bhlk", h, p["attn"]["wq"], quant), m["rope_theta"])
-    k = _rope(_mm("bld,dhk->bhlk", h, p["attn"]["wk"], quant), m["rope_theta"])
-    v = _mm("bld,dhk->bhlk", h, p["attn"]["wv"], quant)
+    h = rms(x, p["ln1"], eps)
+    q = rope(mm("bld,dhk->bhlk", h, p["attn"]["wq"], quant), m["rope_theta"])
+    k = rope(mm("bld,dhk->bhlk", h, p["attn"]["wk"], quant), m["rope_theta"])
+    v = mm("bld,dhk->bhlk", h, p["attn"]["wv"], quant)
     k = jnp.repeat(k, group, axis=1)
     v = jnp.repeat(v, group, axis=1)
-    s = _mm("bhqd,bhkd->bhqk", q, k, quant) * (m["head_dim"] ** -0.5)
+    s = mm("bhqd,bhkd->bhqk", q, k, quant) * (m["head_dim"] ** -0.5)
     n = x.shape[1]
     qpos, kpos = jnp.arange(n)[:, None], jnp.arange(n)[None, :]
     mask = kpos <= qpos
     if m.get("window"):
         mask &= kpos > qpos - m["window"]
     s = jnp.where(mask, s, -jnp.inf)
-    y = _mm("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v, quant)
-    x = x + _mm("bhlk,hkd->bld", y, p["attn"]["wo"], quant)
-    h = _rms(x, p["ln2"], eps)
-    g = _mm("bld,df->blf", h, p["ffn"]["w_gate"], quant)
-    u = _mm("bld,df->blf", h, p["ffn"]["w_up"], quant)
-    return x + _mm("blf,fd->bld", jax.nn.silu(g) * u, p["ffn"]["w_down"],
-                   quant)
+    y = mm("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v, quant)
+    x = x + mm("bhlk,hkd->bld", y, p["attn"]["wo"], quant)
+    h = rms(x, p["ln2"], eps)
+    g = mm("bld,df->blf", h, p["ffn"]["w_gate"], quant)
+    u = mm("bld,df->blf", h, p["ffn"]["w_up"], quant)
+    return x + mm("blf,fd->bld", jax.nn.silu(g) * u, p["ffn"]["w_down"],
+                  quant)
 
 
 def nll_sum(params, tokens, labels, m, quant=None):
@@ -137,151 +99,64 @@ def nll_sum(params, tokens, labels, m, quant=None):
     for i in range(m["n_layers"]):
         x = layer(jax.tree_util.tree_map(lambda a: a[i], params["blocks"]),
                   x)
-    x = _rms(x, params["final_ln"], m["norm_eps"])
+    x = rms(x, params["final_ln"], m["norm_eps"])
     if m["tie_embeddings"]:
-        logits = _mm("bld,vd->blv", x, params["embed"]["embedding"], quant)
+        logits = mm("bld,vd->blv", x, params["embed"]["embedding"], quant)
     else:
-        logits = _mm("bld,dv->blv", x, params["lm_head"], quant)
+        logits = mm("bld,dv->blv", x, params["lm_head"], quant)
     lse = jax.nn.logsumexp(logits, axis=-1)
     picked = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
     return jnp.sum(lse - picked)
 
 
-@functools.partial(jax.jit, static_argnames=("mkey", "quant"))
-def _block_grad(params, tokens, labels, mkey, quant):
-    m = dict(mkey)
-    f32 = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), params)
-    return jax.value_and_grad(nll_sum)(f32, tokens, labels, m, quant)
+# ----------------------------------------------------------------- counts
+def matmul_params(m: dict) -> int:
+    """Parameters that take part in a matrix multiplication per token:
+    every projection of every layer and the output head (tied or not);
+    the embedding gather and the norms do no multiplication."""
+    d, hd = m["d_model"], m["head_dim"]
+    attn = d * m["n_heads"] * hd * 2 + d * m["n_kv_heads"] * hd * 2
+    mlp = 3 * d * m["d_ff"]
+    return m["n_layers"] * (attn + mlp) + m["vocab"] * d
 
 
-def loss_and_grad(params, tokens, labels, m, quant=None, block_rows=1):
-    """Mean loss and its fp32 gradient, accumulated over blocks of rows so
-    that the attention scores of one block at a time fit the device."""
-    mkey = tuple(sorted(m.items()))
-    total, grads = 0.0, None
-    for r in range(0, tokens.shape[0], block_rows):
-        l, g = _block_grad(params, tokens[r:r + block_rows],
-                           labels[r:r + block_rows], mkey, quant)
-        total += float(l)
-        grads = g if grads is None else jax.tree_util.tree_map(
-            jnp.add, grads, g)
-    n = tokens.size
-    return total / n, jax.tree_util.tree_map(lambda g: g / n, grads)
+def param_count(m: dict, norms: bool = True) -> int:
+    """All parameters of a dense GQA decoder: embedding, untied head when
+    present, every layer's projections, and (``norms``) its RMSNorm gains."""
+    d = m["d_model"]
+    n = matmul_params(m) + (0 if m["tie_embeddings"] else m["vocab"] * d)
+    if norms:
+        n += m["n_layers"] * 2 * d + d
+    return n
 
 
-# -------------------------------------------------------------- optimizer
-def learning_rate(opt: dict, count: int) -> float:
-    """Linear warm-up, then cosine decay to ``total_steps``."""
-    warm = min(1.0, (count + 1) / max(1, opt["warmup_steps"]))
-    frac = (count - opt["warmup_steps"]) / max(
-        1, opt["total_steps"] - opt["warmup_steps"])
-    frac = min(max(frac, 0.0), 1.0)
-    return opt["lr"] * warm * 0.5 * (1.0 + math.cos(math.pi * frac))
+def attended_keys(m: dict, seq_len: int) -> float:
+    """Mean number of keys a query attends to under the causal (and
+    sliding-window) mask."""
+    w = m.get("window") or seq_len
+    total = sum(min(i + 1, w) for i in range(seq_len))
+    return total / seq_len
 
 
-@functools.partial(jax.jit, static_argnames=("okey",))
-def _adamw(params, grads, mom, vel, count, lr, okey):
-    opt = dict(okey)
-    b1, b2 = opt["beta1"], opt["beta2"]
-    leaves = jax.tree_util.tree_leaves(grads)
-    gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g)) for g in leaves))
-    scale = jnp.where(gnorm > opt["clip_norm"], opt["clip_norm"] / gnorm, 1.0)
-    c = (count + 1).astype(jnp.float32)
-    bc1, bc2 = 1.0 - b1 ** c, 1.0 - b2 ** c
-    clipped = jax.tree_util.tree_map(lambda g: g * scale, grads)
-    mom = jax.tree_util.tree_map(lambda m, g: b1 * m + (1 - b1) * g,
-                                 mom, clipped)
-    vel = jax.tree_util.tree_map(lambda v, g: b2 * v + (1 - b2) * g * g,
-                                 vel, clipped)
-
-    def step(p, m, v):
-        w = p.astype(jnp.float32)
-        upd = (m / bc1) / (jnp.sqrt(v / bc2) + opt["eps"])
-        return (w - lr * (upd + opt["weight_decay"] * w)).astype(p.dtype)
-
-    return jax.tree_util.tree_map(step, params, mom, vel), mom, vel, clipped
+def model_flops_per_token(m: dict, seq_len: int) -> float:
+    """Forward plus backward FLOPs per token (3x forward; no recompute)."""
+    dense = 2 * matmul_params(m)
+    attn = 4 * m["n_layers"] * m["n_heads"] * m["head_dim"] * attended_keys(
+        m, seq_len)
+    return 3 * (dense + attn)
 
 
-@jax.jit
-def zero_embed_rows(grads):
-    """Rows of the input embedding's gradient that are exactly zero."""
-    return jnp.all(grads["embed"]["embedding"] == 0, axis=1)
-
-
-@jax.jit
-def leaf_norms(tree):
-    return jnp.stack([jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32))))
-                      for x in jax.tree_util.tree_leaves(tree)])
-
-
-@jax.jit
-def diff_norms(a, b):
-    return jnp.stack([
-        jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32)
-                                    - y.astype(jnp.float32))))
-        for x, y in zip(jax.tree_util.tree_leaves(a),
-                        jax.tree_util.tree_leaves(b))])
-
-
-def train_readings(seed: int, m: dict, opt: dict, data: dict, steps: int,
-                   quant: Optional[str] = None, rows=None,
-                   block_rows: int = 1) -> dict:
-    """The reference's losses of the first ``steps`` steps, the per-leaf
-    norms of the first step's clipped gradient and which rows of its input
-    embedding are zero (tokens the batch does not hold), and the per-leaf
-    norms of the parameters' change after ``steps`` steps."""
-    params = init_params(seed, m)
-    first = params
-    mom = jax.tree_util.tree_map(
-        lambda p: jnp.zeros(p.shape, jnp.float32), params)
-    vel = mom
-    okey = tuple(sorted((k, v) for k, v in opt.items()
-                        if isinstance(v, (int, float))))
-    losses, grad_norms, zero_rows = [], None, None
-    for step in range(steps):
-        tokens, labels = token_batch(seed, step, m["vocab"], data["seq_len"],
-                                     data["global_batch"], data["zipf_a"])
-        if rows is not None:
-            tokens, labels = tokens[rows], labels[rows]
-        loss, grads = loss_and_grad(params, jnp.asarray(tokens),
-                                    jnp.asarray(labels), m, quant, block_rows)
-        losses.append(loss)
-        lr = learning_rate(opt, step)
-        params, mom, vel, clipped = _adamw(params, grads, mom, vel,
-                                           jnp.int32(step), jnp.float32(lr),
-                                           okey)
-        if step == 0:
-            grad_norms = np.asarray(leaf_norms(clipped), np.float64)
-            zero_rows = np.asarray(zero_embed_rows(clipped))
-        del grads, clipped
-    change = np.asarray(diff_norms(params, first), np.float64)
-    return {"losses": losses, "grad_norms": grad_norms,
-            "embed_zero_rows": zero_rows, "change_norms": change}
-
-
-# ------------------------------------------------------------- comparison
-def worst_leaf_gap(prog, ref, include=None) -> float:
-    """Largest gap between the program's and the reference's norm of one
-    leaf, against the reference's norm of that leaf or of the median leaf,
-    whichever is larger."""
-    prog, ref = np.asarray(prog, np.float64), np.asarray(ref, np.float64)
-    floor = float(np.median(ref))
-    gaps = np.abs(prog - ref) / np.maximum(ref, floor)
-    if include is not None:
-        gaps = gaps[np.asarray(include, bool)]
-    return float(gaps.max())
-
-
-def moving_leaves(ref_grad_norms) -> np.ndarray:
-    """Leaves whose reference gradient is above a thousandth of the median
-    leaf's; the rest move under Adam by round-off alone."""
-    g = np.asarray(ref_grad_norms, np.float64)
-    return g >= 1e-3 * float(np.median(g))
-
-
-def loss_gap(prog_losses, ref_losses) -> float:
-    """Largest relative gap between the program's and the reference's loss
-    over the compared steps."""
-    p = np.asarray(prog_losses, np.float64)
-    r = np.asarray(ref_losses, np.float64)
-    return float(np.max(np.abs(p - r) / np.abs(r)))
+def leaf_bytes(m: dict, moments_bytes: int = 4) -> list:
+    """Bytes of every leaf of the training state: bf16 params, fp32 Adam
+    moments m and v, and the int32 step count."""
+    d, hd, f, v = m["d_model"], m["head_dim"], m["d_ff"], m["vocab"]
+    layer = [d, d, d * m["n_heads"] * hd, d * m["n_kv_heads"] * hd,
+             d * m["n_kv_heads"] * hd, m["n_heads"] * hd * d, d * f, d * f,
+             f * d]
+    params = [v * d, d] + [m["n_layers"] * x for x in layer]
+    if not m["tie_embeddings"]:
+        params.append(d * v)
+    out = [2 * p for p in params]
+    out += [moments_bytes * p for p in params] * 2
+    out.append(4)
+    return out

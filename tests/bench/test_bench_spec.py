@@ -110,6 +110,23 @@ def test_configs_and_cells_have_their_files():
         assert "limits" in cell.params
 
 
+@pytest.mark.parametrize("entry", SPEC["configs"], ids=lambda c: c["name"])
+def test_configs_name_a_reference_that_keeps_the_contract(entry):
+    """Each configuration names its architecture's reference: a file under
+    the benchmark's paths that defines ``init_params``, ``nll_sum``,
+    ``model_flops_per_token`` and ``TINY``."""
+    conf = json.loads((ROOT / entry["file"]).read_text())
+    path = conf["reference"]
+    assert PATH.match(path) and not path.startswith("/") and ".." not in path
+    assert any(path.startswith(p + "/") for p in SPEC["paths"])
+    assert (ROOT / path).is_file()
+    arch = harness.load_reference(ROOT / path)
+    for fn in ("init_params", "nll_sum", "model_flops_per_token"):
+        assert callable(getattr(arch, fn, None)), fn
+    assert isinstance(arch.TINY, dict) and arch.TINY
+    assert set(arch.TINY) <= set(conf["model"])
+
+
 def test_every_cell_reports_setup_another_metric_and_a_layer():
     for w in SPEC["workloads"]:
         cell = harness.load_cell(w["name"])

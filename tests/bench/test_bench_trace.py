@@ -13,6 +13,7 @@ ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 from bench import flops, peaks, tracefile  # noqa: E402
+from bench import reference as dense  # noqa: E402
 
 RECORDED = sorted((ROOT / "bench" / "testdata").glob("*.json.gz"))
 CONFIG = ROOT / "bench" / "configs" / "danube1.8b-pp8vocab-staged-async.json"
@@ -102,7 +103,7 @@ def test_recorded_trace(path):
     assert any(kernel.match(name) for name, _, _ in events["device"])
     runs = tracefile.module_runs_matching(r, snapshot_roofline.PROGRAM)
     conf = json.loads(CONFIG.read_text())
-    leaves = flops.leaf_bytes(conf["model"])
+    leaves = dense.leaf_bytes(conf["model"])
     assert runs and len(runs) % len(leaves) == 0
     saves = len(runs) // len(leaves)
     moved = saves * flops.snapshot_program_bytes(
