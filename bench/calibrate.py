@@ -53,6 +53,7 @@ def program_readings(run: harness.Run, arch: str, steps: int) -> dict:
         if step == steps:
             box["change"] = np.asarray(
                 rt.diff_norms(st["params"], rec.pop("init")), np.float64)
+            harness.read_extra(run, st, rec)
 
     tc = harness.train_config(run, arch, steps, 10 ** 6)
     out = train.run(tc, env=harness.craft_env(run.cell, run.workdir),
@@ -108,7 +109,8 @@ def main(argv=None) -> int:
         full = reference_readings(run)
         t_ref = time.perf_counter() - t0
         if seed in seeds:
-            run.probes = Probes().install()
+            run.probes = Probes(
+                harness.WORKDIR / "craft_trace.jsonl").install()
             try:
                 got = program_readings(run, arch, STEPS)
             finally:

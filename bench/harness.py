@@ -23,7 +23,9 @@ After the window, and after the device peak has been read and the
 program's state freed, the reference recomputes the first steps from the
 seed (``bench/ref_training.py`` with the architecture module that the
 configuration's ``"reference"`` key names), and every compared number is
-printed with its limit.
+printed with its limit.  Where that module defines ``EXTRA_KEY``, the
+program's ``state[EXTRA_KEY]`` (training state that is not a parameter)
+is read where the parameters' change is, and compared as ``extra_gap``.
 """
 from __future__ import annotations
 
@@ -92,13 +94,19 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     if not ref_path.is_file():
         raise ValueError(f"configuration {w['config']!r}: no reference "
                          f"file {config['reference']!r}")
+    params = _load_json(bench / "workloads" / f"{name}.json")
+    key = getattr(load_reference(ref_path), "EXTRA_KEY", None)
+    if key is not None and "extra_gap" not in params["limits"]:
+        raise ValueError(f"bench/workloads/{name}.json: the reference "
+                         f"{config['reference']} keeps training state under "
+                         f"EXTRA_KEY {key!r}, so the cell's limits must hold "
+                         f"\"extra_gap\"")
     e2e = [m for m in spec["end_to_end"] if _applies(m, name, ())]
     names = {m["name"] for m in e2e}
     return Cell(
         name=name, chips=int(w["chips"]), config=config,
         traffic=_load_json(bench / "traffic" / f"{w['traffic']}.json"),
-        params=_load_json(bench / "workloads" / f"{name}.json"),
-        end_to_end=e2e,
+        params=params, end_to_end=e2e,
         per_layer=[m for m in spec["per_layer"]
                    if _applies(m, name, names)],
         reference=ref_path)
@@ -293,6 +301,23 @@ def first_gradient(run: Run, state, rec: dict) -> None:
     rec["embed_zero_rows"] = np.asarray(rt.zero_embed_rows(m))
 
 
+def read_extra(run: Run, state, rec: dict) -> None:
+    """A host copy of the program's ``state[EXTRA_KEY]`` into
+    ``rec["extra"]``, where the configuration's reference defines
+    ``EXTRA_KEY``; a state without that subtree is refused."""
+    import jax
+
+    key = getattr(run.reference, "EXTRA_KEY", None)
+    if key is None:
+        return
+    if key not in state:
+        raise ValueError(f"the program's training state has no {key!r} "
+                         f"subtree (it has {sorted(state)}), which the "
+                         f"reference {run.cell.reference} names as its "
+                         f"EXTRA_KEY")
+    rec["extra"] = jax.device_get(state[key])
+
+
 # --------------------------------------------------------- train and save
 def run_train_save(run: Run, arch: str) -> dict:
     import jax
@@ -325,6 +350,7 @@ def run_train_save(run: Run, arch: str) -> dict:
         if step == 3:
             rec["change_norms"] = np.asarray(rt.diff_norms(
                 st["params"], rec.pop("init_params")), np.float64)
+            read_extra(run, st, rec)
         if step == s0:
             # warm-up save, made durable: loads the snapshot programs that
             # donate the previous staging buffer and fills the second host
@@ -433,7 +459,9 @@ def check_train_save(run: Run, rec: dict) -> None:
 def _check_training(run: Run, losses, rec: dict) -> None:
     """Compare the first steps with the reference: ``losses[i]`` holds the
     program's readings of step i + 1 (several where resumes repeat it);
-    ``rec`` holds the first gradient and the change after the last step."""
+    ``rec`` holds the first gradient, the change after the last step and,
+    where the reference defines ``EXTRA_KEY``, the state beside the
+    parameters after it."""
     from bench import ref_training as rt
 
     data = {"seq_len": run.seq_len, "global_batch": run.batch,
@@ -454,7 +482,7 @@ def training_numbers(got: dict, want: dict) -> dict:
     from bench import ref_training as rt
 
     losses = [x if isinstance(x, list) else [x] for x in got["losses"]]
-    return {
+    out = {
         "loss_gap": max(rt.loss_gap(g, [w] * len(g))
                         for g, w in zip(losses, want["losses"])),
         "grad_gap": rt.worst_leaf_gap(got["grad_norms"],
@@ -465,6 +493,9 @@ def training_numbers(got: dict, want: dict) -> dict:
             got["change_norms"], want["change_norms"],
             rt.moving_leaves(want["grad_norms"])),
     }
+    if "extra" in want:
+        out["extra_gap"] = rt.extra_gap(got["extra"], want["extra"])
+    return out
 
 
 # ------------------------------------------------------------------ resume
@@ -526,8 +557,11 @@ def run_resume(run: Run, arch: str) -> dict:
         return got
 
     warm = resume_once()
+    state = warm.pop("state")
     rec["change_norms"] = np.asarray(rt.diff_norms(
-        warm.pop("state")["params"], rec.pop("init_params")), np.float64)
+        state["params"], rec.pop("init_params")), np.float64)
+    read_extra(run, state, rec)
+    del state
     rec["resumes"] = [warm]
     rec["counters0"] = _counters(run)
     window.start()

@@ -16,6 +16,38 @@ An architecture is a module that a configuration names by its
 * ``TINY`` -- the model keys a CPU test shrinks, with their tiny values; a
   key the configuration leaves null stays null.
 
+Three optional hooks serve a model that trains more than its parameters
+under AdamW on the NLL alone, such as an MoE with a routing correction
+bias and a sequence-wise balance loss (DeepSeek-V3, arXiv:2412.19437
+§2.1.2).  A module that defines none of them runs the plain program: the
+same jitted functions, the same operations in the same order.
+
+* ``EXTRA_KEY`` and ``init_extra(seed, m)`` -- ``EXTRA_KEY`` names the
+  subtree of the program's training state (``state[EXTRA_KEY]``) that
+  holds state which is neither a parameter nor the optimizer's;
+  ``init_extra`` builds that state at step 0 from the seed: a pytree of
+  float32 arrays in the program's layout (the same tree, shapes and
+  dtype).  It is carried through the steps, returned under ``"extra"``
+  and compared with the program's as ``extra_gap``.
+* ``objective(params, extra, tokens, labels, m, quant=None)`` ->
+  ``(total, (nll, stats))`` -- ``total``, a float32 scalar, is what the
+  gradient is taken of; ``nll``, summed as ``nll_sum`` sums it, is the
+  loss reading; ``stats`` is a pytree of float32 arrays for
+  ``update_extra`` (``extra`` is None where ``EXTRA_KEY`` is not
+  defined).  Each is a sum over the rows (sequences) it is given, so that
+  the blocks of rows below change nothing but rounding: every part must
+  add up over rows.  A sequence-wise balance loss does; a batch-wise one,
+  a function of the whole batch's loads, does not and cannot be written
+  here.  The program's loss is ``total / tokens.size``, so a term that
+  the program averages over sequences enters ``total`` times the
+  sequence length.  Without this hook ``total`` and ``nll`` are both
+  ``nll_sum`` and there are no stats.
+* ``update_extra(extra, stats, m)`` -> ``extra`` -- the state's update,
+  which is not by the gradient: it runs once per step, after the AdamW
+  step, on ``stats`` summed over all of that step's row blocks, and
+  returns the new state in the same layout.  Without it the state stays
+  as ``init_extra`` built it.
+
 This module builds the token batches from the seed (Philox zipf tokens),
 computes the loss and its gradient in float32 at the highest matmul
 precision, in blocks of rows, and takes the AdamW step the configuration
@@ -23,7 +55,7 @@ states (bf16 parameters between steps, fp32 moments, no master copy).
 ``quant="fp8"`` computes every matrix product on operands rounded to
 float8 e4m3 with a per-tensor scale: the control that has to come out as
 not correct.  ``rows`` restricts the loss to a subset of each batch's rows
-(the half-batch fault).
+(the half-batch fault).  Both reach the hooks as they reach ``nll_sum``.
 """
 from __future__ import annotations
 
@@ -88,19 +120,37 @@ def _block_grad(params, tokens, labels, arch, mkey, quant):
     return jax.value_and_grad(arch.nll_sum)(f32, tokens, labels, m, quant)
 
 
-def loss_and_grad(arch, params, tokens, labels, m, quant=None, block_rows=1):
+@functools.partial(jax.jit, static_argnames=("arch", "mkey", "quant"))
+def _block_objective_grad(params, extra, tokens, labels, arch, mkey, quant):
+    m = dict(mkey)
+    f32 = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), params)
+    (_, (nll, stats)), grads = jax.value_and_grad(
+        arch.objective, has_aux=True)(f32, extra, tokens, labels, m, quant)
+    return nll, grads, stats
+
+
+def loss_and_grad(arch, params, tokens, labels, m, quant=None, block_rows=1,
+                  extra=None):
     """Mean loss and its fp32 gradient, accumulated over blocks of rows so
-    that the attention scores of one block at a time fit the device."""
+    that the attention scores of one block at a time fit the device, and
+    the ``objective`` hook's stats summed over the blocks (None without
+    the hook, whose gradient is then of the NLL)."""
     mkey = tuple(sorted(m.items()))
-    total, grads = 0.0, None
+    total, grads, stats = 0.0, None, None
     for r in range(0, tokens.shape[0], block_rows):
-        l, g = _block_grad(params, tokens[r:r + block_rows],
-                           labels[r:r + block_rows], arch, mkey, quant)
+        block = tokens[r:r + block_rows], labels[r:r + block_rows]
+        if hasattr(arch, "objective"):
+            l, g, s = _block_objective_grad(params, extra, *block, arch,
+                                            mkey, quant)
+            stats = s if stats is None else jax.tree_util.tree_map(
+                jnp.add, stats, s)
+        else:
+            l, g = _block_grad(params, *block, arch, mkey, quant)
         total += float(l)
         grads = g if grads is None else jax.tree_util.tree_map(
             jnp.add, grads, g)
     n = tokens.size
-    return total / n, jax.tree_util.tree_map(lambda g: g / n, grads)
+    return total / n, jax.tree_util.tree_map(lambda g: g / n, grads), stats
 
 
 # -------------------------------------------------------------- optimizer
@@ -162,10 +212,13 @@ def train_readings(arch, seed: int, m: dict, opt: dict, data: dict,
                    block_rows: int = 1) -> dict:
     """The reference's losses of the first ``steps`` steps, the per-leaf
     norms of the first step's clipped gradient and which rows of its input
-    embedding are zero (tokens the batch does not hold), and the per-leaf
-    norms of the parameters' change after ``steps`` steps."""
+    embedding are zero (tokens the batch does not hold), the per-leaf
+    norms of the parameters' change after ``steps`` steps, and, where the
+    architecture defines ``EXTRA_KEY``, its state beside the parameters
+    after ``steps`` steps (``"extra"``)."""
     params = arch.init_params(seed, m)
     first = params
+    extra = arch.init_extra(seed, m) if hasattr(arch, "EXTRA_KEY") else None
     mom = jax.tree_util.tree_map(
         lambda p: jnp.zeros(p.shape, jnp.float32), params)
     vel = mom
@@ -177,20 +230,26 @@ def train_readings(arch, seed: int, m: dict, opt: dict, data: dict,
                                      data["global_batch"], data["zipf_a"])
         if rows is not None:
             tokens, labels = tokens[rows], labels[rows]
-        loss, grads = loss_and_grad(arch, params, jnp.asarray(tokens),
-                                    jnp.asarray(labels), m, quant, block_rows)
+        loss, grads, stats = loss_and_grad(
+            arch, params, jnp.asarray(tokens), jnp.asarray(labels), m, quant,
+            block_rows, extra)
         losses.append(loss)
         lr = learning_rate(opt, step)
         params, mom, vel, clipped = _adamw(params, grads, mom, vel,
                                            jnp.int32(step), jnp.float32(lr),
                                            okey)
+        if hasattr(arch, "update_extra"):
+            extra = arch.update_extra(extra, stats, m)
         if step == 0:
             grad_norms = np.asarray(leaf_norms(clipped), np.float64)
             zero_rows = np.asarray(zero_embed_rows(clipped))
         del grads, clipped
     change = np.asarray(diff_norms(params, first), np.float64)
-    return {"losses": losses, "grad_norms": grad_norms,
-            "embed_zero_rows": zero_rows, "change_norms": change}
+    out = {"losses": losses, "grad_norms": grad_norms,
+           "embed_zero_rows": zero_rows, "change_norms": change}
+    if extra is not None:
+        out["extra"] = extra
+    return out
 
 
 # ------------------------------------------------------------- comparison
@@ -203,6 +262,27 @@ def worst_leaf_gap(prog, ref, include=None) -> float:
     gaps = np.abs(prog - ref) / np.maximum(ref, floor)
     if include is not None:
         gaps = gaps[np.asarray(include, bool)]
+    return float(gaps.max())
+
+
+def extra_gap(prog, ref) -> float:
+    """Largest norm of the difference between the program's and the
+    reference's state beside the parameters in one leaf, against the
+    reference's norm of that leaf or of the median leaf, whichever is
+    larger (the rule of ``worst_leaf_gap``).  The two trees must have one
+    layout."""
+    p_def, r_def = (jax.tree_util.tree_structure(t) for t in (prog, ref))
+    p_shapes, r_shapes = ([np.shape(x) for x in jax.tree_util.tree_leaves(t)]
+                          for t in (prog, ref))
+    if p_def != r_def or p_shapes != r_shapes:
+        raise ValueError(f"the program's state {p_def}, shapes {p_shapes}, "
+                         f"is not in the reference's layout {r_def}, shapes "
+                         f"{r_shapes}")
+    diff = np.asarray(diff_norms(prog, ref), np.float64)
+    norm = np.asarray(leaf_norms(ref), np.float64)
+    den = np.maximum(norm, float(np.median(norm)))
+    with np.errstate(divide="ignore", invalid="ignore"):
+        gaps = np.where(diff == 0, 0.0, diff / den)
     return float(gaps.max())
 
 

@@ -114,7 +114,8 @@ def test_configs_and_cells_have_their_files():
 def test_configs_name_a_reference_that_keeps_the_contract(entry):
     """Each configuration names its architecture's reference: a file under
     the benchmark's paths that defines ``init_params``, ``nll_sum``,
-    ``model_flops_per_token`` and ``TINY``."""
+    ``model_flops_per_token`` and ``TINY``, and any optional hook with
+    what it needs."""
     conf = json.loads((ROOT / entry["file"]).read_text())
     path = conf["reference"]
     assert PATH.match(path) and not path.startswith("/") and ".." not in path
@@ -125,6 +126,11 @@ def test_configs_name_a_reference_that_keeps_the_contract(entry):
         assert callable(getattr(arch, fn, None)), fn
     assert isinstance(arch.TINY, dict) and arch.TINY
     assert set(arch.TINY) <= set(conf["model"])
+    # the optional hooks of bench/ref_training.py come in their pairs
+    if hasattr(arch, "EXTRA_KEY"):
+        assert callable(getattr(arch, "init_extra", None))
+    if hasattr(arch, "update_extra"):
+        assert hasattr(arch, "EXTRA_KEY")
 
 
 def test_every_cell_reports_setup_another_metric_and_a_layer():
